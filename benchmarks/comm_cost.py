@@ -99,4 +99,6 @@ def run(fast: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.api import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     run()

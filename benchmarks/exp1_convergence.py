@@ -40,6 +40,8 @@ def run(fast: bool = False, engine: str = "host", svd_backend: str = "host"):
 
 
 if __name__ == "__main__":
+    from repro.api import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--engine", default="host", choices=["host", "scan"])

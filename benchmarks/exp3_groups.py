@@ -103,6 +103,8 @@ def scenarios(fast: bool = False, seed: int = 0):
 
 
 if __name__ == "__main__":
+    from repro.api import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     import sys
     if "--scenarios" in sys.argv:
         scenarios(fast="--fast" in sys.argv)

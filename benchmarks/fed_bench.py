@@ -12,8 +12,15 @@ Speedup_warm = host dispatch / scan warm (steady state); speedup_cold =
 host total / scan cold (one-shot).
 
   PYTHONPATH=src python benchmarks/fed_bench.py [--fast] [--out PATH]
+  PYTHONPATH=src python benchmarks/fed_bench.py --sharded [--fast]
 
 Writes results/BENCH_fed.json (cited in DESIGN.md / ROADMAP.md).
+
+--sharded runs on VIRTUAL CPU devices only: its parent never initializes a
+jax backend and starts one worker per device count with JAX_PLATFORMS=cpu
+and --xla_force_host_platform_device_count, so on a machine with a chip no
+worker contends for it. The on-chip sharded-vs-unsharded comparison is
+`python chip_smoke.py --four-chips`.
 """
 from __future__ import annotations
 
@@ -184,9 +191,10 @@ def bench_sharded_case(d: int, rounds: int, *, warm_iters: int = 3,
 
 
 def run_sharded_parent(fast: bool, out_path: str) -> None:
-    """Spawn one subprocess per virtual-device count (XLA_FLAGS must be set
-    before jax initializes, hence processes, not threads), collect rows,
-    assert the sharded-engine invariants, write BENCH_fed_sharded.json."""
+    """Spawn one CPU subprocess per virtual-device count (XLA_FLAGS must be
+    set before jax initializes, hence processes, not threads), collect rows,
+    assert the sharded-engine invariants, write BENCH_fed_sharded.json.
+    This process stays off every jax backend."""
     import subprocess
     import sys
     import tempfile
@@ -200,6 +208,7 @@ def run_sharded_parent(fast: bool, out_path: str) -> None:
     rows: List[Dict] = []
     for devices in (1, 8):
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={devices}")
         for d, rounds, agg in cases:
@@ -252,7 +261,7 @@ def run_sharded_parent(fast: bool, out_path: str) -> None:
 
     out = {
         "bench": "fed_engine_sharded_vs_vmap",
-        "platform": jax.default_backend(),
+        "platform": "cpu",
         "jax": jax.__version__,
         "invariants": {
             "agreement_tol": "1e-5 at rounds<=5; 1e-2 sanity bound on the "
@@ -333,4 +342,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.api import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     main()

@@ -72,5 +72,7 @@ def run(fast: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.api import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     for name, us, derived in run():
         print(f"{name},{us:.1f},{derived}")

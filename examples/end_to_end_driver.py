@@ -76,4 +76,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.api import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     main()

@@ -84,6 +84,8 @@ def run(dataset: str, d: int = 5, c: int = 4, n_ij: int = 100, seed: int = 0,
 
 
 if __name__ == "__main__":
+    from repro.api import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="battery_small",
                     choices=sorted(PAPER_MLPS))

@@ -5,4 +5,6 @@
 from repro.launch.serve import main
 
 if __name__ == "__main__":
+    from repro.api import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     main()

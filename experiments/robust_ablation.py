@@ -294,4 +294,6 @@ def main(argv: Optional[List[str]] = None) -> Dict:
 
 
 if __name__ == "__main__":
+    from repro.api import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     main()

@@ -21,8 +21,9 @@ instead of waiting for someone to re-run a benchmark by hand.
 
   PYTHONPATH=src:. python experiments/sweep.py [--fast] [--out-dir results]
 
-Set FEDDCL_COMPILATION_CACHE=<dir> to also persist XLA executables across
-processes (CI does; see repro.api.enable_persistent_compilation_cache).
+XLA executables persist across processes in the compilation cache
+(`JAX_COMPILATION_CACHE_DIR`, else `.jax_cache/` in the checkout; see
+repro.api.enable_persistent_compilation_cache).
 """
 from __future__ import annotations
 
@@ -199,9 +200,8 @@ def main():
     args = ap.parse_args()
 
     from repro.api import enable_persistent_compilation_cache
-    cc = enable_persistent_compilation_cache()
-    if cc:
-        print(f"[sweep] persistent XLA compilation cache: {cc}")
+    print("[sweep] persistent XLA compilation cache: "
+          f"{enable_persistent_compilation_cache()}")
 
     import jax
     meta = {"platform": jax.default_backend(), "jax": jax.__version__,

@@ -53,12 +53,14 @@ def main(argv=None) -> int:
     from repro.analysis.hlo_audit import (BakedDataError, CompileCounter,
                                           assert_no_baked_data,
                                           collective_census)
+    from repro.api import enable_persistent_compilation_cache
     from repro.core import federated
     from repro.core.federated import lower_fl_plan, make_fl_plan, pad_silo_data
     from repro.launch.mesh import make_host_mesh
     from repro.models import mlp
     from repro.optim import adamw
 
+    enable_persistent_compilation_cache()
     # sized so every padded tensor (and the closure-captured control slice)
     # clears --min-elems: 3 silos x 7 batches x 8 x 16 features
     rng = np.random.default_rng(0)

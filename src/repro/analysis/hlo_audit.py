@@ -21,16 +21,16 @@ code inside individual tests:
     `BakedDataError` naming them. Splat constants (zeros/ones fills from
     padding or init) carry no information and pass at any size.
 
-`CompileCounter` — a recompile sentinel: counts XLA backend compilations
-    inside a `with` block by hooking `jax._src.compiler.backend_compile`.
-    Warm-path tests assert `count == 0` directly instead of inferring
-    "no recompile" from a 29–60× timing ratio that goes flaky on loaded
-    CI runners (tests/test_plan_cache.py).
+`CompileCounter` — a recompile sentinel: counts executable builds inside
+    a `with` block through a public `jax.monitoring` listener. Warm-path
+    tests assert `count == 0` directly instead of inferring "no recompile"
+    from a 29–60× timing ratio that goes flaky on loaded CI runners
+    (tests/test_plan_cache.py).
 """
 from __future__ import annotations
 
 import re
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 COLLECTIVE_KINDS = ("all-reduce", "all-gather", "all-to-all",
                     "collective-permute", "reduce-scatter")
@@ -53,15 +53,22 @@ def _as_compiled_text(lowered: Any) -> str:
 def collective_census(lowered: Any,
                       kinds: Tuple[str, ...] = COLLECTIVE_KINDS
                       ) -> Dict[str, int]:
-    """Histogram of collective ops in a compiled module, keyed by kind,
-    zero-count kinds omitted. Async `-start` forms count once (`-done`
-    lines don't match, so start/done pairs aren't double-counted) — the
-    exact counting rule the sharded tests pinned their asserted counts
-    with, now in one place."""
+    """Histogram of collective operands in a compiled module, keyed by kind,
+    zero-count kinds omitted. Each collective instruction counts once per
+    operand: XLA's combiner passes merge several same-kind collectives into
+    ONE tuple-shaped instruction (`(f32[8], f32[4,8], …) all-reduce(%a, %b,
+    …)`), so counting operands keeps the pinned counts (one per param leaf
+    plus one for the loss) independent of how the compiler grouped them.
+    TPU layouts carry parentheses (`{1,0:T(8,128)}`), so a tuple type is
+    matched lazily up to the `) kind(` that closes it. Async `-start` forms
+    count once (`-done` lines don't match, so start/done pairs aren't
+    double-counted). Pre-optimization HLO (`Lowered.as_text(dialect="hlo")`)
+    passes as a string and counts the collectives the program asks for."""
     txt = _as_compiled_text(lowered)
     out: Dict[str, int] = {}
     for kind in kinds:
-        n = len(re.findall(rf"= \S+ {kind}(?:-start)?\(", txt))
+        n = sum(max(args.count("%"), 1) for args in re.findall(
+            rf"= (?:\(.*?\)|\S+) {kind}(?:-start)?\(([^()]*)\)", txt))
         if n:
             out[kind] = n
     return out
@@ -141,34 +148,33 @@ def assert_no_baked_data(lowered: Any, min_elems: int = 1024) -> None:
             "(core/federated.make_fl_plan)")
 
 
-class CompileCounter:
-    """Count XLA backend compilations inside a `with` block.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-    Hooks `jax._src.compiler.backend_compile` — the single funnel every
-    fresh executable build passes through in jax 0.4.x (jit C++ cache
-    hits, plan-cache hits, and persistent-compilation-cache disk hits all
-    bypass it). `count == 0` therefore IS "the warm path rebuilt
-    nothing", with none of the timing-ratio flakiness. Reentrant
-    `with` blocks nest; the hook is removed on exit even on error."""
+
+class CompileCounter:
+    """Count executable builds inside a `with` block.
+
+    Listens (`jax.monitoring`) for the duration event JAX records around
+    every executable it obtains for a lowered computation: a fresh XLA
+    compile or a persistent-compilation-cache disk read. In-memory hits (the
+    jit C++ cache, plan-cache hits) obtain no executable and record nothing,
+    so `count == 0` IS "the warm path built nothing", with none of the
+    timing-ratio flakiness. Counters nest; the listener is removed on exit
+    even on error."""
 
     def __init__(self) -> None:
         self.count = 0
-        self._orig = None
+
+    def _listener(self, event: str, duration: float, **kwargs) -> None:
+        if event == BACKEND_COMPILE_EVENT:
+            self.count += 1
 
     def __enter__(self) -> "CompileCounter":
-        import jax._src.compiler as _compiler
-        self._compiler = _compiler
-        self._orig = _compiler.backend_compile
-        orig = self._orig
-
-        def counting_backend_compile(*args, **kwargs):
-            self.count += 1
-            return orig(*args, **kwargs)
-
-        _compiler.backend_compile = counting_backend_compile
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(self._listener)
         return self
 
     def __exit__(self, *exc) -> None:
-        self._compiler.backend_compile = self._orig
-        self._orig = None
+        import jax.monitoring
+        jax.monitoring.unregister_event_duration_listener(self._listener)
         return None

@@ -7,8 +7,8 @@ first ``fit()`` of a given shape bucket pays the scan-engine trace+compile
 (~1 s on CPU); every later ``fit()`` whose padded shapes land in the same
 bucket reuses the executable and costs milliseconds (the plan cache,
 core/federated.py, DESIGN.md §6). Across processes, the persistent XLA
-compilation cache (``FEDDCL_COMPILATION_CACHE``) turns even the first call
-of a fresh process into a disk hit.
+compilation cache (`enable_persistent_compilation_cache`) turns even the
+first call of a fresh process into a disk hit.
 
     from repro.api import FedDCL
     model = FedDCL(m_tilde=8, rounds=20, local_epochs=4, task="regression")
@@ -22,12 +22,13 @@ returned ``setup`` is the full FedDCLSetup (mappings, G's, comm log) and
 """
 from __future__ import annotations
 
-import os
+import pathlib
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.experimental.compilation_cache import compilation_cache
 
 from repro.core import protocol
 from repro.core.federated import (FLResult, PlanCache, default_plan_cache,
@@ -36,41 +37,37 @@ from repro.core.protocol import FedDCLSetup
 from repro.models import mlp
 from repro.optim import adamw
 
-_COMPILE_CACHE_ENABLED: Optional[str] = None
+# fixed in-checkout default: the cache directory is part of what makes a
+# later run hit, so it must never be a temporary or per-process path
+DEFAULT_COMPILATION_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
+
+_COMPILE_CACHE_DIR: Optional[str] = None
 
 
-def enable_persistent_compilation_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point XLA's persistent compilation cache at `cache_dir` (default: the
-    ``FEDDCL_COMPILATION_CACHE`` env var) so compiled executables survive
-    process boundaries — CI and benchmark sweeps set the env var and every
-    fresh process starts warm. No-op when neither is set; idempotent;
-    returns the active directory (or None).
+def enable_persistent_compilation_cache() -> str:
+    """Turn on XLA's persistent compilation cache so compiled executables
+    survive process boundaries; returns the active directory. Idempotent.
 
+    The directory is JAX's own setting (``JAX_COMPILATION_CACHE_DIR``) when
+    one is configured, and then nothing else is set; otherwise it is
+    `DEFAULT_COMPILATION_CACHE_DIR`, a fixed path inside the checkout.
     Thresholds are dropped to zero because the FL-phase programs are small,
     fast-compiling HLO by XLA's heuristics yet dominate our cold time.
     """
-    global _COMPILE_CACHE_ENABLED
-    cache_dir = cache_dir or os.environ.get("FEDDCL_COMPILATION_CACHE")
+    global _COMPILE_CACHE_DIR
+    cache_dir = jax.config.jax_compilation_cache_dir
     if not cache_dir:
-        return _COMPILE_CACHE_ENABLED
-    if _COMPILE_CACHE_ENABLED == cache_dir:
+        cache_dir = DEFAULT_COMPILATION_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if _COMPILE_CACHE_DIR == cache_dir:
         return cache_dir
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for flag, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                      ("jax_persistent_cache_min_compile_time_secs", 0)):
-        try:
-            jax.config.update(flag, val)
-        except AttributeError:       # older jax: thresholds keep defaults
-            pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     # jax latches cache-off at the first compile of the process; reset so
     # enabling mid-process (any compile may already have happened) works
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass
-    _COMPILE_CACHE_ENABLED = cache_dir
+    compilation_cache.reset_cache()
+    _COMPILE_CACHE_DIR = cache_dir
     return cache_dir
 
 

@@ -162,21 +162,25 @@ class DeviceBackend:
         return self.topk_svd_many([np.asarray(A)], k)[0]
 
     def topk_svd_many(self, mats: Sequence[np.ndarray], k: int):
+        """One batched Gram+eigh launch per distinct matrix width. Zero
+        columns would leave the exact top-k untouched but change the f32
+        eigh's roundoff (3.4e-5 on the eigenvectors on a v5e), so a matrix
+        is never padded: its factors do not depend on which other matrices
+        share the call — what lets onboarding equal a from-scratch run."""
         import jax.numpy as jnp
         from repro.kernels.gram import ops as gram_ops
-        padded, _ = pad_ragged(mats)
-        # batch at the widest feasible rank, then clamp per matrix exactly
-        # like HostBackend.topk_svd (min(k, *A.shape)) — for a narrower
-        # matrix the slots past its width hold zero-eigenvalue pairs, so
-        # slicing the leading k_b columns recovers its own top-k.
-        k_eff = int(min(k, padded.shape[1], padded.shape[2]))
-        U, s, V = gram_ops.gram_eigh_topk_batched(jnp.asarray(padded), k_eff)
-        U, s, V = np.asarray(U), np.asarray(s), np.asarray(V)
-        out = []
+        by_width: dict = {}
         for b, m in enumerate(mats):
-            k_b = int(min(k, *m.shape))
-            out.append(_fix_signs(U[b][:, :k_b], s[b][:k_b],
-                                  V[b, : m.shape[1], :k_b]))
+            by_width.setdefault(m.shape[1], []).append(b)
+        out: list = [None] * len(mats)
+        for idx in by_width.values():
+            stack = np.stack([np.asarray(mats[b], np.float32) for b in idx])
+            # clamp exactly like HostBackend.topk_svd (min(k, *A.shape))
+            k_b = int(min(k, *stack.shape[1:]))
+            U, s, V = (np.asarray(x) for x in gram_ops.gram_eigh_topk_batched(
+                jnp.asarray(stack), k_b))
+            for pos, b in enumerate(idx):
+                out[b] = _fix_signs(U[pos], s[pos], V[pos])
         return out
 
     def solve_G_many(self, anchors: Sequence[np.ndarray],

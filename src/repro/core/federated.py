@@ -69,11 +69,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.optim import Optimizer, apply_updates
 from repro.shardingx.policy import batch_spec
+
+# Aggregation sums over the silo axis are matmuls; at TPU default precision
+# an f32 matmul runs one bf16 pass, which would round every averaged
+# parameter to ~3 significant digits each round.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ==========================================================================
@@ -180,8 +184,7 @@ def make_dropout_schedule(seed: int, rounds: int, num_silos: int,
     """Per-round silo availability mask, (rounds, num_silos) float32 {0,1}.
 
     Drawn ON HOST (numpy; never inside a compiled program, let alone a
-    shard_map manual region — the same rule as the batch-permutation
-    schedule, see make_fl_plan's miscompile note) so both engines and every
+    shard_map manual region) so both engines and every
     sharding of the plan consume the identical schedule. Each (round, silo)
     is an independent Bernoulli(1 - rate) draw; empty silos (sizes 0) are
     never available, and every round is guaranteed at least one available
@@ -267,7 +270,7 @@ def masked_trimmed_mean(vals: jnp.ndarray, mask: jnp.ndarray,
     t = jnp.clip(t, 0, jnp.maximum((k - 1) // 2, 0))
     idx = jnp.arange(d, dtype=jnp.int32)
     keep = ((idx >= t) & (idx < k - t)).astype(jnp.float32)
-    kept = jnp.tensordot(keep, s, axes=(0, 0))
+    kept = jnp.tensordot(keep, s, axes=(0, 0), precision=HIGHEST)
     return kept / jnp.maximum(k - 2 * t, 1).astype(jnp.float32)
 
 
@@ -281,7 +284,8 @@ def krum_select(flat: jnp.ndarray, mask: jnp.ndarray,
     d = flat.shape[0]
     f32 = flat.astype(jnp.float32)
     sq = jnp.sum(f32 * f32, axis=1)
-    dist = sq[:, None] + sq[None, :] - 2.0 * (f32 @ f32.T)
+    dist = sq[:, None] + sq[None, :] - 2.0 * jnp.matmul(
+        f32, f32.T, precision=HIGHEST)
     valid = mask > 0
     pair = valid[:, None] & valid[None, :] & ~jnp.eye(d, dtype=bool)
     dist = jnp.where(pair, jnp.maximum(dist, 0.0), _MASK_BIG)
@@ -417,8 +421,8 @@ def _make_sgd_step(batch_loss, opt: Optimizer, masked: bool = False):
 def _weighted_silo_mean(stacked: Any, wn: jnp.ndarray) -> Any:
     """Sample-weighted mean over the leading silo dim (wn sums to 1)."""
     return jax.tree.map(
-        lambda a: jnp.tensordot(wn, a.astype(jnp.float32),
-                                axes=(0, 0)).astype(a.dtype), stacked)
+        lambda a: jnp.tensordot(wn, a.astype(jnp.float32), axes=(0, 0),
+                                precision=HIGHEST).astype(a.dtype), stacked)
 
 
 def _stack_trees(trees: Sequence[Any]) -> Any:
@@ -1039,27 +1043,14 @@ def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
     vstep = jax.vmap(step, in_axes=(0, 0, 0, 0, 0, None))
     gather = jax.vmap(lambda a, i: a[i])                 # (d, n_slots, …) × (d, B)
 
-    def make_schedule(key, rnds):
-        """Batch schedule for the given rounds, (r, d, E, n_slots) over ALL
-        d silos. Sharded plans compute this OUTSIDE the shard_map region and
-        pass it in sharded over the silo dim — each shard then scans its own
-        silos' GLOBAL streams. Two reasons: it keeps the shard-local program
-        free of jax.random entirely, and it works around a jax 0.4.x
-        miscompile where the sort inside jax.random.permutation, lowered
-        within a shard_map manual region and consumed by a lax.scan, is
-        rewritten with partition-id so every shard silently gets shard 0's
-        permutations (verified on CPU; tests/test_fed_sharded.py would catch
-        it as a ~1e-2 disagreement)."""
-        return jax.vmap(
-            lambda r: round_perms(key, r, d, local_epochs, n_slots))(rnds)
-
     def reduce_tree(stacked: Any, wn) -> Any:
         """fedavg_sync in plan form: the weighted mean over the GLOBAL silo
         axis — a local f32 tensordot over this shard's silos plus (when
         sharded) the hierarchical round-boundary psum; wn sums to 1 over
         all d silos, so the psum of partial weighted sums IS the mean."""
         part = jax.tree.map(
-            lambda a: jnp.tensordot(wn, a.astype(jnp.float32), axes=(0, 0)),
+            lambda a: jnp.tensordot(wn, a.astype(jnp.float32), axes=(0, 0),
+                                    precision=HIGHEST),
             stacked)
         if axes is not None:
             part = _psum_tree(part, axes)
@@ -1105,9 +1096,7 @@ def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
         level when sharded) or — for robust aggregators — a cross-silo
         all_gather followed by the masked robust statistic, computed
         redundantly per shard on identical gathered inputs (replicated
-        output, no further collective; the §7 sort-in-shard_map miscompile
-        concern does not bite here because every shard sorts the SAME
-        gathered array)."""
+        output, no further collective)."""
         sp = apply_silo_scale(sp, gp, scale)
         if not robust:
             return reduce_tree(sp, wr_r)
@@ -1186,27 +1175,24 @@ def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
         return (rep(carry[0]), silo)
 
     def round_body_of(key, emit, X, Y, w, scale):
-        """Scan body over (sched, wr) xs: sched is either this round's
-        (dl, E, n_slots) schedule slice (sharded — the PRNG ran outside the
-        manual region, see make_schedule) or the scalar round index
-        (unsharded / fedsgd — the schedule is derived in-scan exactly as
-        before); wr_r is this round's (dl,) aggregation-weight row."""
+        """Scan body over (rnd, wr) xs: rnd is the scalar round index and
+        wr_r this round's (dl,) aggregation-weight row. The batch schedule
+        is derived in-scan from (key, rnd, GLOBAL silo id) — a sharded plan
+        offsets its local silos by the shard index, so every shard draws
+        exactly the streams the single-device plan draws for those silos."""
         def round_body(c, x):
-            sx, wr_r = x
-            if aggregator == "fedsgd":
-                pr = None
-            elif sx.ndim == 0:
-                pr = round_perms(key, sx, d, local_epochs, n_slots)
-            else:
-                pr = sx
+            rnd, wr_r = x
+            pr = None
+            if aggregator != "fedsgd":
+                dl = X.shape[0]
+                ids = jnp.arange(dl)
+                if axes is not None:
+                    ids = ids + lax.axis_index(axes) * dl
+                pr = round_perms(key, rnd, dl, local_epochs, n_slots,
+                                 silo_ids=ids)
             c, rl, gp = round_step(c, pr, X, Y, w, wr_r, scale)
             return c, emit(rl, gp)
         return round_body
-
-    def sched_for(key, rnds):
-        if axes is None or aggregator == "fedsgd":
-            return rnds, P()
-        return make_schedule(key, rnds), P(None, axes)
 
     if mode in ("none", "stack"):
         emit = (lambda rl, gp: (rl, gp)) if mode == "stack" \
@@ -1214,21 +1200,19 @@ def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
 
         @jax.jit
         def plan(init_params, X, Y, w, wr, scale, key):
-            def whole(init_params, X, Y, w, wr, scale, key, sched):
+            def whole(init_params, X, Y, w, wr, scale, key):
                 carry0 = carry_init_traced(init_params, X.shape[0])
                 c, ys = lax.scan(round_body_of(key, emit, X, Y, w, scale),
-                                 carry0, (sched, wr))
+                                 carry0, (jnp.arange(rounds), wr))
                 return carry_params(c), ys
 
-            sched, sspec = sched_for(key, jnp.arange(rounds))
             if axes is None:
-                return whole(init_params, X, Y, w, wr, scale, key, sched)
+                return whole(init_params, X, Y, w, wr, scale, key)
             sx, sy, sw, swr, ssc = data_specs(X, Y, w)
-            return shard_map(whole, mesh,
-                             in_specs=(P(), sx, sy, sw, swr, ssc, P(),
-                                       sspec),
-                             out_specs=P(), check_rep=False)(
-                init_params, X, Y, w, wr, scale, key, sched)
+            return jax.shard_map(whole, mesh=mesh,
+                                 in_specs=(P(), sx, sy, sw, swr, ssc, P()),
+                                 out_specs=P(), check_vma=False)(
+                init_params, X, Y, w, wr, scale, key)
 
         return plan
 
@@ -1238,19 +1222,18 @@ def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
     def chunk_step(carry, X, Y, w, wr, scale, key, rnd0, nr):
         emit = lambda rl, gp: (rl, gp)
 
-        def whole(carry, X, Y, w, wr, scale, key, sched):
+        def whole(carry, X, Y, w, wr, scale, key, rnd0):
             return lax.scan(round_body_of(key, emit, X, Y, w, scale),
-                            carry, (sched, wr))
+                            carry, (rnd0 + jnp.arange(nr), wr))
 
-        sched, sspec = sched_for(key, rnd0 + jnp.arange(nr))
         if axes is None:
-            return whole(carry, X, Y, w, wr, scale, key, sched)
+            return whole(carry, X, Y, w, wr, scale, key, rnd0)
         sx, sy, sw, swr, ssc = data_specs(X, Y, w)
         cs = carry_specs(carry)
-        return shard_map(whole, mesh,
-                         in_specs=(cs, sx, sy, sw, swr, ssc, P(), sspec),
-                         out_specs=(cs, P()), check_rep=False)(
-            carry, X, Y, w, wr, scale, key, sched)
+        return jax.shard_map(whole, mesh=mesh,
+                             in_specs=(cs, sx, sy, sw, swr, ssc, P(), P()),
+                             out_specs=(cs, P()), check_vma=False)(
+            carry, X, Y, w, wr, scale, key, rnd0)
 
     # CPU has no buffer donation; elsewhere chunks recycle carry buffers
     donate = () if jax.default_backend() == "cpu" else (0,)
@@ -1453,7 +1436,7 @@ def fedavg_sync(silo_params: Any, weights: Optional[jnp.ndarray] = None) -> Any:
         else:
             w = (weights /
                  jnp.maximum(jnp.sum(weights), _DEN_EPS)).astype(jnp.float32)
-            mean = jnp.tensordot(w, pf, axes=(0, 0))[None]
+            mean = jnp.tensordot(w, pf, axes=(0, 0), precision=HIGHEST)[None]
         return jnp.broadcast_to(mean, p.shape).astype(p.dtype)
 
     return jax.tree.map(avg, silo_params)

@@ -21,7 +21,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.gram import ref
-from repro.kernels.gram.kernel import gram_batched_pallas, gram_pallas
+from repro.kernels.gram.kernel import (gram_batched_pallas,
+                                      gram_cross_batched_pallas, gram_pallas)
+
+# Step 3 is held to ≤1e-3 of the NumPy-f64 host backend; at TPU default
+# precision an f32 matmul is one bf16 pass (~2e-3 off on the chip), so every
+# matmul here asks for full f32 passes.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _resolve(backend: str) -> str:
@@ -46,6 +52,16 @@ def gram_batched(a, *, backend: str = "auto"):
     if backend == "ref":
         return ref.gram_batched_reference(a)
     return gram_batched_pallas(a, interpret=(backend == "interpret"))
+
+
+@functools.partial(jax.jit, static_argnames=("backend",))
+def gram_cross_batched(a, b, *, backend: str = "auto"):
+    """a: (B, r, m), b: (B, r, n) -> stacked A_b^T B_b (B, m, n) fp32 in ONE
+    dispatch — the off-diagonal blocks of a Gram grown by new columns."""
+    backend = _resolve(backend)
+    if backend == "ref":
+        return ref.gram_cross_batched_reference(a, b)
+    return gram_cross_batched_pallas(a, b, interpret=(backend == "interpret"))
 
 
 def gram_eigh_topk(a, k: int, *, backend: str = "auto"):
@@ -86,7 +102,8 @@ def eigh_topk_recover_batched(g, a, k: int):
     evals = evals[:, ::-1][:, :k]
     V = evecs[:, :, ::-1][:, :, :k]                   # (B, m, k)
     s = jnp.sqrt(jnp.maximum(evals, 0.0))             # (B, k)
-    U = jnp.einsum("brm,bmk->brk", a.astype(jnp.float32), V)
+    U = jnp.einsum("brm,bmk->brk", a.astype(jnp.float32), V,
+                   precision=HIGHEST)
     U = U / jnp.maximum(s, 1e-12)[:, None, :]
     return U, s, V
 
@@ -99,19 +116,20 @@ def gram_append_blocked(g, a_old, a_new):
     blocks —
 
         [[ g          A_oldᵀA_new ]
-         [ (·)ᵀ       A_newᵀA_new ]]
+         [ A_newᵀA_old A_newᵀA_new ]]
 
     O(r·W·w) work instead of the O(r·(W+w)²) full reduction, batched over
-    a leading group axis.
+    a leading group axis. Every new block goes through the Gram kernel, so the
+    grown Gram is the one a from-scratch reduction of [A_old A_new] gives.
 
     g: (B, W, W);  a_old: (B, r, W);  a_new: (B, r, w) -> (B, W+w, W+w).
     """
-    a_old = a_old.astype(jnp.float32)
-    a_new = a_new.astype(jnp.float32)
-    cross = jnp.einsum("brw,brv->bwv", a_old, a_new)      # (B, W, w)
-    new = jnp.einsum("brv,bru->bvu", a_new, a_new)        # (B, w, w)
-    top = jnp.concatenate([g.astype(jnp.float32), cross], axis=2)
-    bot = jnp.concatenate([jnp.swapaxes(cross, 1, 2), new], axis=2)
+    # the lower block is reduced itself, not transposed: the kernel's Gram
+    # is not bitwise symmetric, and eigh reads both triangles
+    top = jnp.concatenate([g.astype(jnp.float32),
+                           gram_cross_batched(a_old, a_new)], axis=2)
+    bot = jnp.concatenate([gram_cross_batched(a_new, a_old),
+                           gram_batched(a_new)], axis=2)
     return jnp.concatenate([top, bot], axis=1)
 
 
@@ -129,7 +147,7 @@ def apply_G_batched(x, g):
     slice away. No masks needed.
     """
     return jnp.einsum("unm,umh->unh", x.astype(jnp.float32),
-                      g.astype(jnp.float32))
+                      g.astype(jnp.float32), precision=HIGHEST)
 
 
 @jax.jit
@@ -201,6 +219,6 @@ def solve_G_from_factors(q, rr, z, col_mask=None):
         col_mask = jnp.ones((b, m_max), dtype=bool)
     z_aug = jnp.concatenate(
         [z, jnp.zeros((b, m_max, z.shape[-1]), z.dtype)], axis=1)
-    rhs = jnp.einsum("bnm,bnh->bmh", q, z_aug)
+    rhs = jnp.einsum("bnm,bnh->bmh", q, z_aug, precision=HIGHEST)
     G = jax.scipy.linalg.solve_triangular(rr, rhs, lower=False)
     return G * col_mask[:, :, None]

@@ -12,5 +12,10 @@ def gram_reference(a: jnp.ndarray) -> jnp.ndarray:
 
 def gram_batched_reference(a: jnp.ndarray) -> jnp.ndarray:
     """a: (B, r, m) -> (B, m, m) fp32."""
-    af = a.astype(jnp.float32)
-    return jnp.einsum("brm,brn->bmn", af, af)
+    return gram_cross_batched_reference(a, a)
+
+
+def gram_cross_batched_reference(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """a: (B, r, m), b: (B, r, n) -> (B, m, n) fp32."""
+    return jnp.einsum("brm,brn->bmn", a.astype(jnp.float32),
+                      b.astype(jnp.float32))

@@ -33,10 +33,17 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref):
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)          # (L, V)
     lw = lw_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)          # (K,)
+    u = u_ref[0].astype(jnp.float32)          # (1, K)
 
     L = r.shape[0]
-    c = jnp.cumsum(lw, axis=0)                # inclusive log-decay
+    idx = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    jdx = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    # inclusive log-decay cumsum as a lower-triangular matmul (Mosaic has
+    # no cumsum); f32-exact passes keep the decays exp(±c) accurate
+    tri = (idx >= jdx).astype(jnp.float32)
+    c = jax.lax.dot_general(tri, lw, (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     cs = c - lw                               # exclusive
     r_t = r * jnp.exp(cs)
     k_t = k * jnp.exp(-c)
@@ -45,10 +52,8 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref):
         r_t, k_t, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                         # (L, L)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    jdx = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
     A = jnp.where(idx > jdx, A, 0.0)
-    diag = jnp.sum(r * k * u[None, :], axis=-1)          # (L,)
+    diag = jnp.sum(r * k * u, axis=-1)                   # (L,)
 
     state = state_ref[...]                    # (K, V)
     y = (
@@ -60,15 +65,29 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref):
     )
     o_ref[0] = y.astype(o_ref.dtype)
 
-    k_end = k * jnp.exp(c[-1:, :] - c)
-    state_ref[...] = state * jnp.exp(c[-1, :])[:, None] + jax.lax.dot_general(
+    last = c[L - 1:L, :]                      # (1, K) chunk-total decay
+    k_end = k * jnp.exp(last - c)
+    # state rows scale by exp(last): a diagonal matmul, since Mosaic has no
+    # cheap (1, K) -> (K, 1) relayout
+    K = state.shape[0]
+    decay = (jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
+             == jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)
+             ).astype(jnp.float32) * jnp.exp(last)
+    state_ref[...] = jax.lax.dot_general(
+        decay, state, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ) + jax.lax.dot_general(
         k_end, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv6_pallas(r, k, v, log_w, u, *, chunk: int = 16, interpret: bool = False):
-    """r/k/log_w: (BH, S, K); v: (BH, S, V); u: (BH, K). -> fp32 (BH, S, V)."""
+    """r/k/log_w: (BH, S, K); v: (BH, S, V); u: (BH, K). -> fp32 (BH, S, V).
+
+    u travels as (BH, 1, K) so its block's last two dims equal the array's
+    — Mosaic refuses a (1, K) block of a 2-D (BH, K) array."""
     BH, S, K = r.shape
     V = v.shape[-1]
     L = min(chunk, S)
@@ -77,7 +96,7 @@ def wkv6_pallas(r, k, v, log_w, u, *, chunk: int = 16, interpret: bool = False):
 
     seq_spec = pl.BlockSpec((1, L, K), lambda g, c: (g, c, 0))
     val_spec = pl.BlockSpec((1, L, V), lambda g, c: (g, c, 0))
-    u_spec = pl.BlockSpec((1, K), lambda g, c: (g, 0))
+    u_spec = pl.BlockSpec((1, 1, K), lambda g, c: (g, 0, 0))
 
     return pl.pallas_call(
         _wkv6_kernel,
@@ -88,4 +107,4 @@ def wkv6_pallas(r, k, v, log_w, u, *, chunk: int = 16, interpret: bool = False):
         # persistent recurrent state across the sequential chunk axis
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, log_w, u)
+    )(r, k, v, log_w, u[:, None, :])

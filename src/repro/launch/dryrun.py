@@ -31,6 +31,10 @@ from repro.launch import roofline
 from repro.launch.mesh import make_production_mesh, num_silos
 from repro.launch.specs import make_plan, resolve_arch_for_shape
 
+# The chip the dry-run models: the production meshes are v5e pods, compiled
+# here on placeholder host devices, so the roofline takes v5e's peaks.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def run_pair(arch: str, shape_name: str, *, multi_pod: bool, mode: str,
              out_dir: str | None, verbose: bool = True,
@@ -92,7 +96,7 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool, mode: str,
     silo_block = 256 if multi_pod else 16
     rec = roofline.analyze(
         compiled, resolve_arch_for_shape(cfg, shape), shape, plan.kind,
-        chips=chips, silo_block=silo_block,
+        chips=chips, device_kind=TARGET_DEVICE_KIND, silo_block=silo_block,
         local_steps=tc.federated.local_steps if plan.kind == "fed_local" else 1)
     ma_scan = compiled_scan.memory_analysis()
     rec["memory"] = {
@@ -179,4 +183,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.api import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     sys.exit(main())

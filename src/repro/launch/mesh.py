@@ -24,24 +24,11 @@ import jax
 import numpy as np
 
 
-def _axis_kwargs(n: int) -> dict:
-    # jax >= 0.5 wants explicit AxisType; pinned 0.4.37 has neither the
-    # enum nor the make_mesh kwarg — feature-detect instead of version-gate
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
-
-
-def _make_mesh(shape, axes):
-    try:
-        return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
-    except TypeError:                           # make_mesh without axis_types
-        return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1, data: Optional[int] = None):

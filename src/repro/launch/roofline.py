@@ -1,9 +1,8 @@
 """Roofline-term extraction from a compiled dry-run artifact.
 
-Hardware model (prescribed — TPU v5e-class):
-    peak   197 TFLOP/s bf16 per chip
-    HBM    819 GB/s per chip
-    ICI    ~50 GB/s per link per chip
+Hardware model: per-chip peaks keyed by the jax `device_kind` of the chip
+the program was compiled for (`PEAKS`); a kind missing from the table is an
+error, never a default.
 
 Terms (seconds, per step, per chip — cost_analysis() on the partitioned
 module is PER-DEVICE, verified empirically in this container):
@@ -23,11 +22,26 @@ import math
 import re
 from typing import Any, Dict
 
-HW = {
-    "peak_flops": 197e12,       # bf16 FLOP/s per chip
-    "hbm_bw": 819e9,            # B/s per chip
-    "link_bw": 50e9,            # B/s per link
+# Published peaks per chip. Source for "TPU v5 lite" (v5e): Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# of inter-chip interconnect over 4 links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,   # bf16 FLOP/s per chip
+        "hbm_bw": 819e9,        # B/s per chip
+        "link_bw": 50e9,        # B/s per link
+    },
 }
+
+
+def peaks_for(device_kind: str) -> Dict[str, float]:
+    """The published peaks of one chip of `device_kind`; raises on a kind
+    with no published entry."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "s32": 4, "u32": 4,
@@ -145,8 +159,9 @@ def cross_block_bytes(hlo_text: str, block: int, num_devices: int) -> int:
     return total
 
 
-def analyze(compiled, cfg, shape, kind: str, *, chips: int,
+def analyze(compiled, cfg, shape, kind: str, *, chips: int, device_kind: str,
             local_steps: int = 1, silo_block: int = 0) -> Dict[str, Any]:
+    hw = peaks_for(device_kind)
     cost = compiled.cost_analysis() or {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
@@ -156,9 +171,9 @@ def analyze(compiled, cfg, shape, kind: str, *, chips: int,
     bytes_dev = float(cost.get("bytes accessed", 0.0))
     coll_dev = float(coll.get("total", 0))
 
-    compute_s = flops_dev / HW["peak_flops"]
-    memory_s = bytes_dev / HW["hbm_bw"]
-    collective_s = coll_dev / HW["link_bw"]
+    compute_s = flops_dev / hw["peak_flops"]
+    memory_s = bytes_dev / hw["hbm_bw"]
+    collective_s = coll_dev / hw["link_bw"]
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dominant = max(terms, key=terms.get)
@@ -171,6 +186,7 @@ def analyze(compiled, cfg, shape, kind: str, *, chips: int,
         **({"cross_silo_bytes_per_device": xs_bytes,
             "silo_block": silo_block} if xs_bytes is not None else {}),
         "arch": cfg.name,
+        "device_kind": device_kind,
         "shape": shape.name,
         "kind": kind,
         "chips": chips,

@@ -21,30 +21,17 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax._src import mesh as mesh_lib
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 
 
 def _physical_mesh():
-    try:
-        from jax._src import mesh as mesh_lib
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if not pm.empty:
-            return pm
-    except Exception:  # pragma: no cover
-        pass
-    return None
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """The mesh of an enclosing `with mesh:` block (jax exposes this legacy
+    context only privately), or None."""
+    pm = mesh_lib.thread_resources.env.physical_mesh
+    return None if pm.empty else pm
 
 
 def apply_moe_ep(p, x: jnp.ndarray, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -131,10 +118,10 @@ def apply_moe_ep(p, x: jnp.ndarray, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.
     down_spec = P("model", d_ax, None)     # (E, f, d): FSDP on f
     rb = p.get("router_bias")
     aux_axes = tuple(batch_axes) + ("model",)
-    fn = _shard_map(
-        local_fn, mesh,
+    fn = jax.shard_map(
+        local_fn, mesh=mesh,
         in_specs=(x_spec, P(), P(), gate_spec, gate_spec, down_spec),
-        out_specs=(x_spec, P(aux_axes)),
+        out_specs=(x_spec, P(aux_axes)), check_vma=False,
     )
     out, aux = fn(x, p["router"], rb if rb is not None else jnp.zeros((0,)),
                   p["w_gate"], p["w_up"], p["w_down"])

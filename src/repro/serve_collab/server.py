@@ -85,7 +85,8 @@ def serve_step(params, M, mu, x, tix):
     Padded rows carry tix 0 and produce garbage the server slices away.
     """
     z = x - mu[tix]                                   # (B, m)
-    h = jnp.einsum("bm,bmh->bh", z, M[tix])           # (B, m̂)
+    h = jnp.einsum("bm,bmh->bh", z, M[tix],           # (B, m̂)
+                   precision=jax.lax.Precision.HIGHEST)
     return mlp.mlp_forward(params, h)
 
 

@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
+from jax._src import mesh as mesh_lib
 from jax.sharding import PartitionSpec as P
 
 Axis = Union[None, str, Tuple[str, ...]]
@@ -19,21 +20,13 @@ Axis = Union[None, str, Tuple[str, ...]]
 
 def _mesh_axes() -> dict:
     # `with mesh:` sets the legacy thread-resources context (what
-    # with_sharding_constraint's spec-only form consumes).
-    try:
-        from jax._src import mesh as mesh_lib
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if not pm.empty:
-            return dict(zip(pm.axis_names, pm.devices.shape))
-    except Exception:       # pragma: no cover
-        pass
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and getattr(m, "axis_names", None):
-            return dict(zip(m.axis_names, m.axis_sizes))
-    except Exception:       # pragma: no cover
-        pass
-    return {}
+    # with_sharding_constraint's spec-only form consumes); `jax.set_mesh`
+    # sets the abstract mesh. jax exposes the former only privately.
+    pm = mesh_lib.thread_resources.env.physical_mesh
+    if not pm.empty:
+        return dict(zip(pm.axis_names, pm.devices.shape))
+    m = jax.sharding.get_abstract_mesh()
+    return dict(zip(m.axis_names, m.axis_sizes))
 
 
 import contextlib

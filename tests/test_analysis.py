@@ -9,6 +9,7 @@ baked-constant detection with a closure-baked positive control, and the
 CompileCounter recompile sentinel.
 """
 import json
+import pathlib
 import re
 import subprocess
 import sys
@@ -24,7 +25,7 @@ from repro.analysis.hlo_audit import (COLLECTIVE_KINDS, BakedDataError,
                                       CompileCounter, assert_no_baked_data,
                                       collective_census, find_baked_constants)
 
-REPO = "/root/repo"
+REPO = str(pathlib.Path(__file__).resolve().parents[1])
 
 
 def _lint(src):
@@ -307,6 +308,19 @@ def test_census_matches_inline_regex_on_synthetic_hlo():
                                      "collective-permute", "reduce-scatter"}
 
 
+def test_census_counts_operands_of_combined_collectives():
+    """XLA's combiner merges same-kind collectives into ONE tuple-shaped
+    instruction; the census counts its operands, so a combined all-reduce
+    of 3 leaves counts 3, exactly like 3 separate ones."""
+    combined = """
+  %all-reduce = (f32[8]{0}, f32[4,8]{1,0}, f32[]) all-reduce(%a, %b, %c), replica_groups={{0,1}}
+  %g0 = f32[8]{0} get-tuple-element(%all-reduce), index=0
+  %ags = (f32[4]{0}, f32[8]{0}) all-gather-start(%p), dimensions={0}
+  %agd = f32[8]{0} all-gather-done(%ags)
+"""
+    assert collective_census(combined) == {"all-reduce": 3, "all-gather": 1}
+
+
 def test_census_accepts_lowered_and_single_device_is_empty():
     low = jax.jit(lambda x: (x @ x.T).sum()).lower(
         jnp.zeros((8, 8), jnp.float32))
@@ -390,9 +404,12 @@ def test_compile_counter_counts_builds_not_hits():
 
 
 def test_compile_counter_restores_patch_on_exit():
-    import jax._src.compiler as _compiler
-
-    before = _compiler.backend_compile
-    with CompileCounter():
-        assert _compiler.backend_compile is not before
-    assert _compiler.backend_compile is before
+    """The listener is removed on exit, also when the block raises: builds
+    after the block no longer count."""
+    with pytest.raises(RuntimeError):
+        with CompileCounter() as cc:
+            raise RuntimeError("boom")
+    with CompileCounter() as outer:
+        jax.jit(lambda x: x * 7.0 - 1.0)(jnp.ones(5)).block_until_ready()
+    assert outer.count >= 1
+    assert cc.count == 0

@@ -9,6 +9,7 @@ subprocess tests force 8 virtual devices regardless, so ragged / non
 divisible silo counts and the collective-structure invariant are proven on
 every run.
 """
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -26,6 +27,7 @@ from repro.models import mlp
 from repro.optim import adamw, sgd
 
 DEV = jax.device_count()
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _linear_silos(sizes, m=4, seed=0):
@@ -232,7 +234,7 @@ def test_sharded_8dev_agreement_and_collective_structure():
                        capture_output=True, text=True, timeout=600,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
                             "JAX_PLATFORMS": "cpu"},
-                       cwd="/root/repo")
+                       cwd=REPO)
     assert r.returncode == 0, r.stderr[-3000:]
     for agg in ("fedavg", "fedprox", "fedsgd",
                 "median", "trimmed_mean", "krum"):
@@ -276,7 +278,7 @@ def test_make_host_mesh_validation_names_device_count():
                        capture_output=True, text=True, timeout=600,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
                             "JAX_PLATFORMS": "cpu"},
-                       cwd="/root/repo")
+                       cwd=REPO)
     assert r.returncode == 0, r.stderr[-3000:] or r.stdout
     assert "RAISES_WITH_COUNT" in r.stdout, r.stdout
     assert "MODEL_TOO_BIG_OK" in r.stdout, r.stdout

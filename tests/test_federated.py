@@ -1,5 +1,6 @@
 """Federated tier: host simulation semantics + mesh-level collective
 structure (the paper's 'no iterative cross-silo traffic' made checkable)."""
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -13,6 +14,8 @@ from repro.core.federated import (fedavg_average, fedavg_sync, run_federated,
                                   silo_replicate)
 from repro.models import mlp
 from repro.optim import adamw, sgd
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _toy_data(n=64, m=4, silos=2, seed=0):
@@ -99,13 +102,8 @@ MESH_SCRIPT = textwrap.dedent("""
     from repro.launch.specs import make_plan
     from repro.launch.roofline import iter_collectives
     cfg = REDUCED["llama3.2-1b"]
-    try:
-        # axis_types / AxisType only exist on jax >= 0.5; the pinned CI jax
-        # (0.4.37) takes the portable spelling below
-        mesh = jax.make_mesh((4, 2), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,)*2)
-    except (AttributeError, TypeError):
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shape = InputShape("t", seq_len=64, global_batch=8, kind="train")
     tc = TrainConfig(model=cfg, shape=shape, remat=False,
                      param_dtype="float32", compute_dtype="float32",
@@ -138,7 +136,7 @@ def test_no_cross_silo_collectives_in_local_step():
                        capture_output=True, text=True, timeout=600,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
                             "JAX_PLATFORMS": "cpu"},
-                       cwd="/root/repo")
+                       cwd=REPO)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "CLEAN" in r.stdout, r.stdout
     assert "SYNC_CROSSES" in r.stdout, r.stdout

@@ -269,20 +269,66 @@ def test_api_fit_warm_path_compiles_nothing():
 # persistent XLA compilation cache wiring
 # ---------------------------------------------------------------------------
 
-def test_persistent_compilation_cache_populates(tmp_path):
+@pytest.fixture
+def restore_cache_config():
+    from jax.experimental.compilation_cache import compilation_cache
+
     from repro import api
 
-    prev = api._COMPILE_CACHE_ENABLED
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev = api._COMPILE_CACHE_DIR
+    yield api
+    jax.config.update("jax_compilation_cache_dir", prev_dir)
+    api._COMPILE_CACHE_DIR = prev
+    compilation_cache.reset_cache()
+
+
+def test_persistent_compilation_cache_populates(tmp_path,
+                                                restore_cache_config):
+    """A directory JAX was given (JAX_COMPILATION_CACHE_DIR lands in this
+    config) stands: the cache is written there and nowhere else."""
+    api = restore_cache_config
     d = str(tmp_path / "xla")
-    try:
-        assert api.enable_persistent_compilation_cache(d) == d
-        assert api.enable_persistent_compilation_cache(d) == d   # idempotent
-        f = jax.jit(lambda x: jnp.tanh(x * 2.0) @ x.T)
-        f(jnp.arange(32.0).reshape(4, 8)).block_until_ready()
-        assert os.listdir(d), "compilation cache dir stayed empty"
-    finally:
-        api._COMPILE_CACHE_ENABLED = prev
-        try:
-            jax.config.update("jax_compilation_cache_dir", prev)
-        except Exception:
-            pass
+    jax.config.update("jax_compilation_cache_dir", d)
+    assert api.enable_persistent_compilation_cache() == d
+    assert api.enable_persistent_compilation_cache() == d   # idempotent
+    assert jax.config.jax_compilation_cache_dir == d
+    f = jax.jit(lambda x: jnp.tanh(x * 2.0) @ x.T)
+    f(jnp.arange(32.0).reshape(4, 8)).block_until_ready()
+    assert os.listdir(d), "compilation cache dir stayed empty"
+
+
+def test_compilation_cache_defaults_to_fixed_checkout_path(
+        restore_cache_config):
+    """Without a JAX setting the cache goes to ONE fixed path inside the
+    checkout — never a temporary or per-process directory."""
+    import pathlib
+
+    api = restore_cache_config
+    jax.config.update("jax_compilation_cache_dir", None)
+    api._COMPILE_CACHE_DIR = None
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert api.DEFAULT_COMPILATION_CACHE_DIR == str(repo / ".jax_cache")
+    assert (api.enable_persistent_compilation_cache()
+            == api.DEFAULT_COMPILATION_CACHE_DIR)
+    assert jax.config.jax_compilation_cache_dir == \
+        api.DEFAULT_COMPILATION_CACHE_DIR
+
+
+def test_compilation_cache_env_var_is_respected(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set from outside wins, in a fresh process."""
+    import pathlib
+    import subprocess
+    import sys
+
+    d = str(tmp_path / "outside")
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "from repro.api import enable_persistent_compilation_cache as e;"
+         "print(e())"],
+        capture_output=True, text=True, timeout=300,
+        cwd=pathlib.Path(__file__).resolve().parents[1],
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": d})
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip().splitlines()[-1] == d

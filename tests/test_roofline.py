@@ -73,4 +73,10 @@ def test_model_flops_kinds():
 
 
 def test_hw_constants_prescribed():
-    assert R.HW == {"peak_flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9}
+    assert R.peaks_for("TPU v5 lite") == {
+        "peak_flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9}
+
+
+def test_unknown_device_kind_raises():
+    with pytest.raises(ValueError, match="TPU v9"):
+        R.peaks_for("TPU v9")
