@@ -1,0 +1,16 @@
+"""Device arrays the builds of the traced window read to the host, per
+build, from the program's own counter."""
+
+COUNTER = "feddcl.device.fetches"
+
+
+def read(ctx):
+    try:
+        from repro import obs
+    except ImportError:                  # a program without counters
+        return None
+    n = obs.snapshot()["counters"].get(COUNTER)
+    units = ctx["counters"].get("builds")
+    if not n or not units:
+        return None
+    return n / units

@@ -27,7 +27,8 @@ over `src/`, `benchmarks/`, `experiments/`, `examples/`, and `scripts/`
   R007  `np.save*` checkpoint writes not going through `mkstemp` —
         guess-renamed sibling names raced concurrent savers (PR 3,
         checkpoint/store.py).
-  R008  `device_get` / `block_until_ready` inside a lax.scan body or a
+  R008  `device_get` / `block_until_ready` (or a module's counted
+        `_fetch` helper) inside a lax.scan body or a
         per-round loop — a host sync per round re-serializes the engine
         the scan work collapsed into one dispatch (PR 4 streams ONE
         transfer per eval chunk instead).
@@ -114,8 +115,9 @@ _COUNTY_RE = re.compile(r"(size|sizes|count|counts|n_samples|num_samples)",
 _WEIGHTY = {"w", "ws", "wb", "wn", "wr", "mask", "masks", "weights"}
 _WEIGHTY_RE = re.compile(r"(weight|mass)", re.IGNORECASE)
 
-# R008: loop headers that advance federated rounds.
+# R008: loop headers that advance federated rounds, and the host syncs.
 _ROUNDY_RE = re.compile(r"(round|rnd)", re.IGNORECASE)
+_HOST_SYNCS = ("device_get", "block_until_ready", "_fetch")
 
 _F32_NAMES = {"numpy.float32", "jax.numpy.float32"}
 
@@ -463,7 +465,7 @@ class _Linter(ast.NodeVisitor):
                            "(checkpoint/store.py is the pattern)")
 
         # R008 — host syncs inside round loops / scan bodies
-        if last in ("device_get", "block_until_ready") and (
+        if last in _HOST_SYNCS and (
                 self._round_loop_depth > 0 or self._in_scan_body > 0):
             where = "a lax.scan body" if self._in_scan_body else \
                 "a per-round loop"
@@ -494,7 +496,7 @@ class _Linter(ast.NodeVisitor):
                     last = sub.func.attr
                 elif isinstance(sub.func, ast.Name):
                     last = sub.func.id
-                if last in ("device_get", "block_until_ready"):
+                if last in _HOST_SYNCS:
                     self._emit("R008", sub,
                                f"{last} inside a lax.scan body forces a "
                                "host sync per scan step")

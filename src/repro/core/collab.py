@@ -33,6 +33,15 @@ from typing import List, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
+
+
+def _fetch(x) -> np.ndarray:
+    """A device array read to the host (counted as
+    ``feddcl.device.fetches``)."""
+    obs.count("feddcl.device.fetches")
+    return np.asarray(x)
+
 
 # --------------------------------------------------------------------------
 # padded-ragged helpers
@@ -177,7 +186,7 @@ class DeviceBackend:
             stack = np.stack([np.asarray(mats[b], np.float32) for b in idx])
             # clamp exactly like HostBackend.topk_svd (min(k, *A.shape))
             k_b = int(min(k, *stack.shape[1:]))
-            U, s, V = (np.asarray(x) for x in gram_ops.gram_eigh_topk_batched(
+            U, s, V = (_fetch(x) for x in gram_ops.gram_eigh_topk_batched(
                 jnp.asarray(stack), k_b))
             for pos, b in enumerate(idx):
                 out[b] = _fix_signs(U[pos], s[pos], V[pos])
@@ -191,7 +200,7 @@ class DeviceBackend:
         G = gram_ops.solve_G_batched(jnp.asarray(padded),
                                      jnp.asarray(Z, jnp.float32),
                                      jnp.asarray(mask), ridge=self.ridge)
-        G = np.asarray(G)
+        G = _fetch(G)
         if not np.all(np.isfinite(G)):
             bad = [b for b in range(len(anchors))
                    if not np.all(np.isfinite(G[b]))]
@@ -213,8 +222,8 @@ class DeviceBackend:
         from repro.kernels.gram import ops as gram_ops
         Xp = pad_ragged2d(Xs)                             # (U, n_max, m̃_max)
         Gp = pad_ragged2d(Gs)                             # (U, m̃_max, m̂)
-        out = np.asarray(gram_ops.apply_G_batched(jnp.asarray(Xp),
-                                                  jnp.asarray(Gp)))
+        out = _fetch(gram_ops.apply_G_batched(jnp.asarray(Xp),
+                                              jnp.asarray(Gp)))
         return [out[u, : x.shape[0], : g.shape[1]]
                 for u, (x, g) in enumerate(zip(Xs, Gs))]
 
@@ -226,7 +235,7 @@ class DeviceBackend:
         agree to fp32 roundoff."""
         import jax.numpy as jnp
         from repro.kernels.gram import ops as gram_ops
-        return np.asarray(gram_ops.gram(jnp.asarray(A, jnp.float32)))
+        return _fetch(gram_ops.gram(jnp.asarray(A, jnp.float32)))
 
     def gram_update_blocked(self, gram: np.ndarray, A_old: np.ndarray,
                             A_new: np.ndarray) -> np.ndarray:
@@ -238,7 +247,7 @@ class DeviceBackend:
             jnp.asarray(gram, jnp.float32)[None],
             jnp.asarray(A_old, jnp.float32)[None],
             jnp.asarray(A_new, jnp.float32)[None])
-        return np.asarray(out[0])
+        return _fetch(out[0])
 
     def topk_svd_from_gram(self, A: np.ndarray, gram: np.ndarray, k: int):
         """Batched eigh+recovery from the maintained Gram (B=1) — the same
@@ -250,8 +259,7 @@ class DeviceBackend:
         U, s, V = gram_ops.eigh_topk_recover_batched(
             jnp.asarray(gram, jnp.float32)[None],
             jnp.asarray(A, jnp.float32)[None], k_eff)
-        return _fix_signs(np.asarray(U[0]), np.asarray(s[0]),
-                          np.asarray(V[0]))
+        return _fix_signs(_fetch(U[0]), _fetch(s[0]), _fetch(V[0]))
 
     def factor_G_many(self, anchors: Sequence[np.ndarray]):
         """ONE batched QR factorization of the (padded) augmented anchor
@@ -293,7 +301,7 @@ class DeviceBackend:
         batched triangular solve from the cached factors."""
         import jax.numpy as jnp
         from repro.kernels.gram import ops as gram_ops
-        G = np.asarray(gram_ops.solve_G_from_factors(
+        G = _fetch(gram_ops.solve_G_from_factors(
             factors["q"], factors["rr"], jnp.asarray(Z, jnp.float32),
             jnp.asarray(factors["mask"])))
         if not np.all(np.isfinite(G)):

@@ -71,6 +71,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.optim import Optimizer, apply_updates
 from repro.shardingx.policy import batch_spec
 
@@ -575,10 +576,6 @@ def default_plan_cache() -> PlanCache:
     return _DEFAULT_PLAN_CACHE
 
 
-def plan_cache_stats() -> Dict[str, int]:
-    return default_plan_cache().stats()
-
-
 def clear_plan_cache() -> None:
     if _DEFAULT_PLAN_CACHE is not None:
         _DEFAULT_PLAN_CACHE.clear()
@@ -698,135 +695,157 @@ def run_federated(
         raise ValueError("mesh=... requires engine='scan' — the host engine "
                          "is a per-batch dispatch loop and cannot shard the "
                          "silo axis")
-    plan_cache: Optional[PlanCache] = None
-    if cache is not None and cache is not False:
-        if engine != "scan":
-            raise ValueError("cache=... requires engine='scan' — the plan "
-                             "cache stores compiled scan-engine executables")
-        plan_cache = cache if isinstance(cache, PlanCache) else default_plan_cache()
-    axes: Optional[Tuple[str, ...]] = None
-    shards = 1
-    if mesh is not None:
-        axes = tuple(silo_axes) if silo_axes else default_silo_axes(mesh)
-        shards = num_silo_shards(mesh, axes)
+    with obs.span("feddcl.fl.prepare"):
+        plan_cache: Optional[PlanCache] = None
+        if cache is not None and cache is not False:
+            if engine != "scan":
+                raise ValueError("cache=... requires engine='scan' — the "
+                                 "plan cache stores compiled scan-engine "
+                                 "executables")
+            plan_cache = cache if isinstance(cache, PlanCache) \
+                else default_plan_cache()
+        axes: Optional[Tuple[str, ...]] = None
+        shards = 1
+        if mesh is not None:
+            axes = tuple(silo_axes) if silo_axes else default_silo_axes(mesh)
+            shards = num_silo_shards(mesh, axes)
 
-    def shard_multiple(d: int) -> int:
-        """Round a silo count up to the silo-shard count (extra silos are
-        empty → exact no-ops under the mask rules)."""
-        return -(-d // shards) * shards
+        def shard_multiple(d: int) -> int:
+            """Round a silo count up to the silo-shard count (extra silos are
+            empty → exact no-ops under the mask rules)."""
+            return -(-d // shards) * shards
 
-    if plan_cache is not None:
-        n_max = max(np.asarray(x).shape[0] for x, _ in silo_data)
-        if aggregator == "fedsgd":
-            bs_eff: Optional[int] = plan_cache.bucket_batches(n_max)
-            min_nb = 1
+        if plan_cache is not None:
+            n_max = max(np.asarray(x).shape[0] for x, _ in silo_data)
+            if aggregator == "fedsgd":
+                bs_eff: Optional[int] = plan_cache.bucket_batches(n_max)
+                min_nb = 1
+            else:
+                bs_eff = batch_size
+                min_nb = plan_cache.bucket_batches(-(-n_max // batch_size))
+            padded = pad_silo_data(
+                silo_data, bs_eff, fill=pad_fill, min_batches=min_nb,
+                min_silos=shard_multiple(
+                    plan_cache.bucket_silos(len(silo_data))))
         else:
-            bs_eff = batch_size
-            min_nb = plan_cache.bucket_batches(-(-n_max // batch_size))
-        padded = pad_silo_data(
-            silo_data, bs_eff, fill=pad_fill, min_batches=min_nb,
-            min_silos=shard_multiple(plan_cache.bucket_silos(len(silo_data))))
-    else:
-        padded = pad_silo_data(
-            silo_data, None if aggregator == "fedsgd" else batch_size,
-            fill=pad_fill,
-            min_silos=shard_multiple(len(silo_data)) if shards > 1 else 0)
-    if per_example is None:
-        per_example = _detect_per_example(loss_fn, init_params, padded)
-    if not per_example and padded.has_padding:
-        raise ValueError(
-            f"silo sizes {padded.sizes.astype(int).tolist()} need padding to "
-            f"{padded.n_slots} slots, which a scalar (batch-mean) loss cannot "
-            "mask — pass a per-example loss (returning a (batch,) vector, "
-            "e.g. models.mlp.mlp_per_example_loss) or equal-size silos "
-            "divisible by batch_size")
-    if availability is not None and dropout_rate:
-        raise ValueError("pass either dropout_rate or an explicit "
-                         "availability matrix, not both")
-    av: Optional[np.ndarray] = None
-    if availability is not None:
-        av = np.asarray(availability, np.float32)
-        if av.shape[0] != rounds or av.shape[1] > padded.num_silos:
+            padded = pad_silo_data(
+                silo_data, None if aggregator == "fedsgd" else batch_size,
+                fill=pad_fill,
+                min_silos=shard_multiple(len(silo_data)) if shards > 1 else 0)
+        if per_example is None:
+            per_example = _detect_per_example(loss_fn, init_params, padded)
+        if not per_example and padded.has_padding:
             raise ValueError(
-                f"availability must be (rounds, num_silos≤{padded.num_silos})"
-                f" for rounds={rounds}; got {av.shape}")
-        if av.shape[1] < padded.num_silos:
-            # bucket-padding silos are empty → never available
-            av = np.concatenate(
-                [av, np.zeros((rounds, padded.num_silos - av.shape[1]),
-                              np.float32)], axis=1)
-    elif dropout_rate:
-        # draw over the REAL silo count so the schedule is invariant to
-        # bucket/shard padding (a d=6 tenant gets the same draws whether the
-        # layout pads to 6, 8, or 16 silos), then zero-pad the columns
-        d_real = len(silo_data)
-        av = make_dropout_schedule(seed, rounds, d_real,
-                                   float(dropout_rate),
-                                   sizes=padded.sizes[:d_real])
-        if padded.num_silos > d_real:
-            av = np.concatenate(
-                [av, np.zeros((rounds, padded.num_silos - d_real),
-                              np.float32)], axis=1)
-    scale_vec: Optional[np.ndarray] = None
-    if silo_scale is not None:
-        s = np.asarray(silo_scale, np.float32).reshape(-1)
-        if s.shape[0] > padded.num_silos:
-            raise ValueError(f"silo_scale has {s.shape[0]} entries for "
-                             f"{padded.num_silos} silos")
-        scale_vec = np.ones(padded.num_silos, np.float32)
-        scale_vec[:s.shape[0]] = s
-    # dropout makes whole rounds all-padding for the dropped silos, so the
-    # exact-no-op step guard must be on even when the layout itself is dense
-    needs_mask = padded.has_padding or (av is not None and not np.all(av > 0))
-    robust = aggregator in ROBUST_AGGREGATORS
-    mu = fedprox_mu if aggregator == "fedprox" else 0.0
-    batch_loss = _make_batch_loss(loss_fn, per_example, mu)
-    if plan_cache is not None:
-        mode = "chunk" if eval_fn is not None else "none"
-        # mesh descriptor: a sharded and an unsharded plan must never alias,
-        # nor two plans on meshes of different shape/axis names/silo axes
-        mesh_sig = None if mesh is None else (
-            tuple(mesh.axis_names),
-            tuple(int(s) for s in mesh.devices.shape), axes)
-        key = (
-            padded.num_silos, padded.num_batches, padded.batch_size,
-            tuple(padded.X.shape[2:]), str(padded.X.dtype),
-            tuple(padded.Y.shape[2:]), str(padded.Y.dtype),
-            _tree_signature(init_params),
-            # chunk plans step nr rounds per dispatch with rounds never
-            # baked into the executable, so they are rounds-agnostic:
-            # rounds=50 and rounds=200 share one cached plan
-            aggregator, None if mode == "chunk" else rounds,
-            local_epochs, bool(reset_opt_per_round),
-            mode, bool(per_example), float(mu),
-            # robust-config enters the EXECUTABLE (trim/f are trace-time
-            # constants), so plans differing only there must never alias;
-            # dropout/scale are runtime ARGUMENTS and stay out of the key
-            (float(trim_frac), int(krum_f)) if robust else None,
-            loss_id if loss_id is not None else ("id", id(loss_fn)),
-            opt_id if opt_id is not None else ("id", id(opt)),
-            mesh_sig,
-        )
-        plan, was_hit = plan_cache.lookup(
-            key,
-            lambda: make_fl_plan(
+                f"silo sizes {padded.sizes.astype(int).tolist()} need "
+                f"padding to {padded.n_slots} slots, which a scalar "
+                "(batch-mean) loss cannot mask — pass a per-example loss "
+                "(returning a (batch,) vector, e.g. "
+                "models.mlp.mlp_per_example_loss) or equal-size silos "
+                "divisible by batch_size")
+        if availability is not None and dropout_rate:
+            raise ValueError("pass either dropout_rate or an explicit "
+                             "availability matrix, not both")
+        av: Optional[np.ndarray] = None
+        if availability is not None:
+            av = np.asarray(availability, np.float32)
+            if av.shape[0] != rounds or av.shape[1] > padded.num_silos:
+                raise ValueError(
+                    f"availability must be (rounds, "
+                    f"num_silos≤{padded.num_silos}) for rounds={rounds}; "
+                    f"got {av.shape}")
+            if av.shape[1] < padded.num_silos:
+                # bucket-padding silos are empty → never available
+                av = np.concatenate(
+                    [av, np.zeros((rounds, padded.num_silos - av.shape[1]),
+                                  np.float32)], axis=1)
+        elif dropout_rate:
+            # draw over the REAL silo count so the schedule is invariant to
+            # bucket/shard padding (a d=6 tenant gets the same draws whether
+            # the layout pads to 6, 8, or 16 silos), then zero-pad the
+            # columns
+            d_real = len(silo_data)
+            av = make_dropout_schedule(seed, rounds, d_real,
+                                       float(dropout_rate),
+                                       sizes=padded.sizes[:d_real])
+            if padded.num_silos > d_real:
+                av = np.concatenate(
+                    [av, np.zeros((rounds, padded.num_silos - d_real),
+                                  np.float32)], axis=1)
+        scale_vec: Optional[np.ndarray] = None
+        if silo_scale is not None:
+            s = np.asarray(silo_scale, np.float32).reshape(-1)
+            if s.shape[0] > padded.num_silos:
+                raise ValueError(f"silo_scale has {s.shape[0]} entries for "
+                                 f"{padded.num_silos} silos")
+            scale_vec = np.ones(padded.num_silos, np.float32)
+            scale_vec[:s.shape[0]] = s
+        # dropout makes whole rounds all-padding for the dropped silos, so
+        # the exact-no-op step guard must be on even when the layout itself
+        # is dense
+        needs_mask = padded.has_padding or (
+            av is not None and not np.all(av > 0))
+        robust = aggregator in ROBUST_AGGREGATORS
+        mu = fedprox_mu if aggregator == "fedprox" else 0.0
+        batch_loss = _make_batch_loss(loss_fn, per_example, mu)
+        obs.count("feddcl.fl.sample_steps",
+                  int(padded.sizes.sum()) * local_epochs * rounds)
+        obs.count("feddcl.fl.slot_steps",
+                  padded.num_silos * padded.n_slots * local_epochs * rounds)
+        plan = was_hit = None
+        if plan_cache is not None:
+            mode = "chunk" if eval_fn is not None else "none"
+            # mesh descriptor: a sharded and an unsharded plan must never
+            # alias, nor two plans on meshes of different shape/axis
+            # names/silo axes
+            mesh_sig = None if mesh is None else (
+                tuple(mesh.axis_names),
+                tuple(int(s) for s in mesh.devices.shape), axes)
+            key = (
+                padded.num_silos, padded.num_batches, padded.batch_size,
+                tuple(padded.X.shape[2:]), str(padded.X.dtype),
+                tuple(padded.Y.shape[2:]), str(padded.Y.dtype),
+                _tree_signature(init_params),
+                # chunk plans step nr rounds per dispatch with rounds never
+                # baked into the executable, so they are rounds-agnostic:
+                # rounds=50 and rounds=200 share one cached plan
+                aggregator, None if mode == "chunk" else rounds,
+                local_epochs, bool(reset_opt_per_round),
+                mode, bool(per_example), float(mu),
+                # robust-config enters the EXECUTABLE (trim/f are trace-time
+                # constants), so plans differing only there must never alias;
+                # dropout/scale are runtime ARGUMENTS and stay out of the key
+                (float(trim_frac), int(krum_f)) if robust else None,
+                loss_id if loss_id is not None else ("id", id(loss_fn)),
+                opt_id if opt_id is not None else ("id", id(opt)),
+                mesh_sig,
+            )
+            plan, was_hit = plan_cache.lookup(
+                key,
+                lambda: make_fl_plan(
+                    num_silos=padded.num_silos,
+                    num_batches=padded.num_batches,
+                    batch_size=padded.batch_size, opt=opt,
+                    batch_loss=batch_loss, rounds=rounds,
+                    local_epochs=local_epochs, aggregator=aggregator,
+                    per_example=per_example,
+                    reset_opt=reset_opt_per_round, collect=mode,
+                    masked=True, mesh=mesh, silo_axes=axes,
+                    trim_frac=trim_frac, krum_f=krum_f),
+                pins=(loss_fn, opt))
+        elif engine == "scan":
+            plan = make_fl_plan(
                 num_silos=padded.num_silos, num_batches=padded.num_batches,
                 batch_size=padded.batch_size, opt=opt, batch_loss=batch_loss,
                 rounds=rounds, local_epochs=local_epochs,
                 aggregator=aggregator, per_example=per_example,
-                reset_opt=reset_opt_per_round, collect=mode,
-                masked=True, mesh=mesh, silo_axes=axes,
-                trim_frac=trim_frac, krum_f=krum_f),
-            pins=(loss_fn, opt))
-        res = _run_scan(batch_loss, init_params, padded, opt=opt,
-                        rounds=rounds, local_epochs=local_epochs,
-                        aggregator=aggregator, seed=seed, eval_fn=eval_fn,
-                        per_example=per_example, reset_opt=reset_opt_per_round,
-                        plan=plan, eval_chunk=eval_chunk,
-                        availability=av, silo_scale=scale_vec)
-        res.cache_stats = {"hit": was_hit, **plan_cache.stats()}
-        return res
-    if engine == "host":
+                reset_opt=reset_opt_per_round,
+                collect="chunk" if eval_fn is not None else "none",
+                masked=needs_mask, mesh=mesh, silo_axes=axes,
+                trim_frac=trim_frac, krum_f=krum_f)
+        if plan is not None:
+            args = _plan_args(padded, seed, rounds, availability=av,
+                              silo_scale=scale_vec)
+    if plan is None:
         return _run_host(batch_loss, init_params, padded, opt=opt,
                          rounds=rounds, local_epochs=local_epochs,
                          aggregator=aggregator, seed=seed, eval_fn=eval_fn,
@@ -835,13 +854,11 @@ def run_federated(
                          availability=av, silo_scale=scale_vec,
                          trim_frac=trim_frac, krum_f=krum_f,
                          masked=needs_mask)
-    return _run_scan(batch_loss, init_params, padded, opt=opt, rounds=rounds,
-                     local_epochs=local_epochs, aggregator=aggregator,
-                     seed=seed, eval_fn=eval_fn, per_example=per_example,
-                     reset_opt=reset_opt_per_round, mesh=mesh,
-                     silo_axes=axes, eval_chunk=eval_chunk,
-                     availability=av, silo_scale=scale_vec,
-                     trim_frac=trim_frac, krum_f=krum_f, masked=needs_mask)
+    res = _run_scan(plan, init_params, args, rounds=rounds, eval_fn=eval_fn,
+                    eval_chunk=eval_chunk)
+    if plan_cache is not None:
+        res.cache_stats = {"hit": was_hit, **plan_cache.stats()}
+    return res
 
 
 # --------------------------------------------------------------------------
@@ -1319,13 +1336,17 @@ def make_scan_runner(batch_loss, padded: PaddedSilos, *, opt, rounds,
     return lambda init_params: plan(init_params, *args)
 
 
-def _run_scan(batch_loss, init_params, padded: PaddedSilos, *, opt, rounds,
-              local_epochs, aggregator, seed, eval_fn, per_example,
-              reset_opt, plan=None, mesh=None, silo_axes=None,
-              eval_chunk: int = 8, availability=None, silo_scale=None,
-              trim_frac: float = 0.2, krum_f: int = 1,
-              masked: Optional[bool] = None) -> FLResult:
-    """Drive a compiled plan over this tenant's padded stack.
+def _fetch(tree):
+    """Device arrays read to the host (counted as ``feddcl.device.fetches``,
+    one per array)."""
+    out = jax.device_get(tree)
+    obs.count("feddcl.device.fetches", len(jax.tree.leaves(out)))
+    return out
+
+
+def _run_scan(plan, init_params, args, *, rounds: int, eval_fn=None,
+              eval_chunk: int = 8) -> FLResult:
+    """Drive a compiled plan over one tenant's plan arguments (`_plan_args`).
 
     With eval_fn, the plan is a StreamedPlan: the FL phase runs in
     eval_chunk-round dispatches that each emit only that chunk's per-round
@@ -1334,61 +1355,40 @@ def _run_scan(batch_loss, init_params, padded: PaddedSilos, *, opt, rounds,
     and dropped — peak extra memory is eval_chunk × |params| regardless of
     rounds. Without eval_fn, one dispatch runs the whole phase and only
     the (rounds,) loss vector comes back."""
-    if masked is None:
-        masked = padded.has_padding or (
-            availability is not None and not np.all(
-                np.asarray(availability) > 0))
-    if plan is None:
-        mode = "chunk" if eval_fn is not None else "none"
-        plan = make_fl_plan(
-            num_silos=padded.num_silos, num_batches=padded.num_batches,
-            batch_size=padded.batch_size, opt=opt, batch_loss=batch_loss,
-            rounds=rounds, local_epochs=local_epochs, aggregator=aggregator,
-            per_example=per_example, reset_opt=reset_opt, collect=mode,
-            masked=masked, mesh=mesh, silo_axes=silo_axes,
-            trim_frac=trim_frac, krum_f=krum_f)
-    args = _plan_args(padded, seed, rounds, availability=availability,
-                      silo_scale=silo_scale)
+    history: List[Dict[str, float]] = []
+    with obs.span("feddcl.fl.plan"):
+        if isinstance(plan, StreamedPlan):
+            X, Y, w, wr, scale, key = args
+            carry = plan.carry_init(init_params)
+            rnd0 = 0
+            while rnd0 < rounds:
+                nr = min(eval_chunk, rounds - rnd0)
+                carry, chunk = plan.step(carry, X, Y, w, wr[rnd0:rnd0 + nr],
+                                         scale, key, jnp.int32(rnd0), nr)
+                # feddcl-lint: disable=R008  one transfer per eval_chunk rounds (the batched form the rule asks for), not one per round
+                host_ls, host_ps = _fetch(chunk)
+                for j in range(nr):
+                    rec = {"round": rnd0 + j, "loss": float(host_ls[j])}
+                    if eval_fn is not None:
+                        rec.update(eval_fn(
+                            jax.tree.map(lambda a: a[j], host_ps)))
+                    history.append(rec)
+                rnd0 += nr
+            return FLResult(params=plan.carry_params(carry), history=history)
 
-    if isinstance(plan, StreamedPlan):
-        X, Y, w, wr, scale, key = args
-        carry = plan.carry_init(init_params)
-        history: List[Dict[str, float]] = []
-        rnd0 = 0
-        while rnd0 < rounds:
-            nr = min(eval_chunk, rounds - rnd0)
-            carry, (ls, ps) = plan.step(carry, X, Y, w, wr[rnd0:rnd0 + nr],
-                                        scale, key, jnp.int32(rnd0), nr)
-            host_ls = np.asarray(ls)
-            # feddcl-lint: disable=R008  one transfer per eval_chunk rounds (the batched form the rule asks for), not one per round
-            host_ps = jax.device_get(ps)
-            for j in range(nr):
-                rec = {"round": rnd0 + j, "loss": float(host_ls[j])}
-                if eval_fn is not None:
-                    rec.update(eval_fn(
-                        jax.tree.map(lambda a: a[j], host_ps)))
-                history.append(rec)
-            rnd0 += nr
-        return FLResult(params=plan.carry_params(carry), history=history)
-
-    gp, ys = plan(init_params, *args)
-    if eval_fn is not None:
-        round_losses, round_params = ys
-        round_losses = np.asarray(round_losses)
-        # one host fetch for the whole (rounds, |params|) stack — the old
-        # per-round tree.map(a[rnd]) forced a device round-trip per leaf
-        # per round (ISSUE 7 satellite); the stacked mode itself remains
-        # the legacy memory-heavy path kept for regression tests.
-        host_params = jax.device_get(round_params)
-        history = []
-        for rnd in range(rounds):
-            rec = {"round": rnd, "loss": float(round_losses[rnd])}
+        gp, ys = plan(init_params, *args)
+        if eval_fn is not None:
+            # one host fetch for the whole (rounds, |params|) stack, not a
+            # device round trip per leaf per round; the stacked mode itself
+            # remains the legacy memory-heavy path kept for regression tests
+            round_losses, host_params = _fetch(ys)
+        else:
+            round_losses = _fetch(ys)
+    for rnd in range(rounds):
+        rec = {"round": rnd, "loss": float(round_losses[rnd])}
+        if eval_fn is not None:
             rec.update(eval_fn(jax.tree.map(lambda a: a[rnd], host_params)))
-            history.append(rec)
-    else:
-        round_losses = np.asarray(ys)
-        history = [{"round": rnd, "loss": float(round_losses[rnd])}
-                   for rnd in range(rounds)]
+        history.append(rec)
     return FLResult(params=gp, history=history)
 
 

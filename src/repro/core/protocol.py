@@ -23,6 +23,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import collab
 from repro.core.anchor import make_anchor
 from repro.core.mappings import LinearMap, fit_mapping
@@ -143,26 +144,32 @@ class FedDCLSetup:
         Returns the new user's index j within group i.
         """
         st = self._require_onboard()
-        be = collab.get_backend(st.backend)
-        j = len(self.mappings[i])
-        X_new = np.asarray(X_new, np.float64)
-        f = fit_mapping(st.mapping_kind, X_new, st.m_tilde,
-                        seed=st.seed * 1009 + i * 101 + j)
-        Xt, At = f(X_new), f(self.anchor)
-        self.comm.log(f"user({i},{j})", f"dc({i})", "X~,A~,Y", Xt, At, Y_new)
-        A_old = np.concatenate(st.inter_A[i], axis=1)
-        st.grams[i] = be.gram_update_blocked(st.grams[i], A_old, At)
-        st.inter_A[i].append(At)
-        st.inter_X[i].append(Xt)
-        self.mappings[i].append(f)
-        fac = be.factor_G_append(st.g_factors[i], At)
-        if fac is None:                 # wider than the factored pad width
-            fac = be.factor_G_many(st.inter_A[i])
-        st.g_factors[i] = fac
-        self._refresh_group_basis(i)
-        self._refresh_central_and_G(changed_groups=(i,))
-        self.collab_Y[i] = np.concatenate(
-            [self.collab_Y[i], np.asarray(Y_new)], axis=0)
+        with obs.span("feddcl.onboard.user"):
+            be = collab.get_backend(st.backend)
+            j = len(self.mappings[i])
+            with obs.span("feddcl.onboard.map"):
+                X_new = np.asarray(X_new, np.float64)
+                f = fit_mapping(st.mapping_kind, X_new, st.m_tilde,
+                                seed=st.seed * 1009 + i * 101 + j)
+                Xt, At = f(X_new), f(self.anchor)
+            self.comm.log(f"user({i},{j})", f"dc({i})", "X~,A~,Y",
+                          Xt, At, Y_new)
+            with obs.span("feddcl.onboard.gram"):
+                A_old = np.concatenate(st.inter_A[i], axis=1)
+                st.grams[i] = be.gram_update_blocked(st.grams[i], A_old, At)
+            st.inter_A[i].append(At)
+            st.inter_X[i].append(Xt)
+            self.mappings[i].append(f)
+            with obs.span("feddcl.onboard.factor"):
+                fac = be.factor_G_append(st.g_factors[i], At)
+                if fac is None:         # wider than the factored pad width
+                    fac = be.factor_G_many(st.inter_A[i])
+            st.g_factors[i] = fac
+            with obs.span("feddcl.onboard.basis"):
+                self._refresh_group_basis(i)
+            self._refresh_central_and_G(changed_groups=(i,))
+            self.collab_Y[i] = np.concatenate(
+                [self.collab_Y[i], np.asarray(Y_new)], axis=0)
         return j
 
     def onboard_silo(self, Xs_new: Sequence[np.ndarray],
@@ -224,22 +231,25 @@ class FedDCLSetup:
         be = collab.get_backend(st.backend)
         for i in changed_groups:
             self.comm.log(f"dc({i})", "fl", "B~", st.bases_B[i])
-        target = collab.central_target(
-            [collab.GroupBasis(B=B) for B in st.bases_B],
-            st.m_hat, st.seed * 57, backend=st.backend)
+        with obs.span("feddcl.onboard.central"):
+            target = collab.central_target(
+                [collab.GroupBasis(B=B) for B in st.bases_B],
+                st.m_hat, st.seed * 57, backend=st.backend)
         self.Z = target.Z
         d = len(st.inter_A)
-        for i in range(d):
-            self.comm.log("fl", f"dc({i})", "Z", target.Z)
-            self.Gs[i] = be.solve_G_factors(st.g_factors[i], target.Z)
-        flat_X = [x for row in st.inter_X for x in row]
-        flat_G = [g for row in self.Gs for g in row]
-        flat_XG = collab.apply_G_all(flat_X, flat_G, backend=st.backend)
-        k = 0
-        for i in range(d):
-            c_i = len(st.inter_X[i])
-            self.collab_X[i] = np.concatenate(flat_XG[k:k + c_i], axis=0)
-            k += c_i
+        with obs.span("feddcl.onboard.solve_G"):
+            for i in range(d):
+                self.comm.log("fl", f"dc({i})", "Z", target.Z)
+                self.Gs[i] = be.solve_G_factors(st.g_factors[i], target.Z)
+        with obs.span("feddcl.onboard.apply_G"):
+            flat_X = [x for row in st.inter_X for x in row]
+            flat_G = [g for row in self.Gs for g in row]
+            flat_XG = collab.apply_G_all(flat_X, flat_G, backend=st.backend)
+            k = 0
+            for i in range(d):
+                c_i = len(st.inter_X[i])
+                self.collab_X[i] = np.concatenate(flat_XG[k:k + c_i], axis=0)
+                k += c_i
 
 
 def run_protocol(
@@ -279,80 +289,98 @@ def run_protocol(
     m = Xs[0][0].shape[1]
     m_hat = m_hat or m_tilde
     comm = CommLog()
+    with obs.span("feddcl.protocol.run"):
+        # ---- Step 1: shared anchor (same seed everywhere) ----------------
+        with obs.span("feddcl.protocol.step1"):
+            if anchor is None:
+                allX = np.concatenate(
+                    [np.concatenate(list(g), axis=0) for g in Xs], axis=0)
+                anchor = make_anchor(
+                    anchor_kind, seed, anchor_r, feat_min=allX.min(0),
+                    feat_max=allX.max(0),
+                    public_sample=allX[:: max(1, len(allX) // 512)])
+            else:
+                anchor = np.asarray(anchor, np.float64)
 
-    # ---- Step 1: shared anchor (same seed everywhere) --------------------
-    if anchor is None:
-        allX = np.concatenate([np.concatenate(list(g), axis=0) for g in Xs],
-                              axis=0)
-        anchor = make_anchor(anchor_kind, seed, anchor_r,
-                             feat_min=allX.min(0), feat_max=allX.max(0),
-                             public_sample=allX[:: max(1, len(allX) // 512)])
-    else:
-        anchor = np.asarray(anchor, np.float64)
+        # ---- Step 2: private maps + intermediate representations ---------
+        mappings: List[List[LinearMap]] = []
+        inter_X: List[List[np.ndarray]] = []
+        inter_A: List[List[np.ndarray]] = []
+        with obs.span("feddcl.protocol.step2"):
+            for i in range(d):
+                row_f, row_x, row_a = [], [], []
+                for j in range(len(Xs[i])):
+                    f = fit_mapping(mapping_kind,
+                                    np.asarray(Xs[i][j], np.float64), m_tilde,
+                                    seed=seed * 1009 + i * 101 + j, W=fixed_W)
+                    row_f.append(f)
+                    Xt, At = f(np.asarray(Xs[i][j], np.float64)), f(anchor)
+                    row_x.append(Xt)
+                    row_a.append(At)
+                    comm.log(f"user({i},{j})", f"dc({i})", "X~,A~,Y",
+                             Xt, At, Ys[i][j])
+                mappings.append(row_f)
+                inter_X.append(row_x)
+                inter_A.append(row_a)
 
-    # ---- Step 2: private maps + intermediate representations -------------
-    mappings: List[List[LinearMap]] = []
-    inter_X: List[List[np.ndarray]] = []
-    inter_A: List[List[np.ndarray]] = []
-    for i in range(d):
-        row_f, row_x, row_a = [], [], []
-        for j in range(len(Xs[i])):
-            f = fit_mapping(mapping_kind, np.asarray(Xs[i][j], np.float64),
-                            m_tilde, seed=seed * 1009 + i * 101 + j, W=fixed_W)
-            row_f.append(f)
-            Xt, At = f(np.asarray(Xs[i][j], np.float64)), f(anchor)
-            row_x.append(Xt)
-            row_a.append(At)
-            comm.log(f"user({i},{j})", f"dc({i})", "X~,A~,Y", Xt, At, Ys[i][j])
-        mappings.append(row_f)
-        inter_X.append(row_x)
-        inter_A.append(row_a)
+        with obs.span("feddcl.protocol.step3"):
+            # ---- Step 3a: intra-group bases -> central server -------------
+            # One batched Gram+eigh launch for all d groups on the device
+            # backend; serial LAPACK loop on host.
+            with obs.span("feddcl.protocol.bases"):
+                bases = collab.intra_group_bases(
+                    inter_A, m_hat, seeds=[seed * 31 + i for i in range(d)],
+                    backend=svd_backend)
+            for i, gb in enumerate(bases):
+                comm.log(f"dc({i})", "fl", "B~", gb.B)
 
-    # ---- Step 3a: intra-group bases -> central server --------------------
-    # One batched Gram+eigh launch for all d groups on the device backend
-    # (zero-padded to the max group width); serial LAPACK loop on host.
-    bases = collab.intra_group_bases(
-        inter_A, m_hat, seeds=[seed * 31 + i for i in range(d)],
-        backend=svd_backend)
-    for i, gb in enumerate(bases):
-        comm.log(f"dc({i})", "fl", "B~", gb.B)
+            # ---- Step 3b: central target Z -> DC servers -------------------
+            with obs.span("feddcl.protocol.central"):
+                target = collab.central_target(bases, m_hat, seed * 57,
+                                               backend=svd_backend)
+            for i in range(d):
+                comm.log("fl", f"dc({i})", "Z", target.Z)
 
-    # ---- Step 3b: central target Z -> DC servers --------------------------
-    target = collab.central_target(bases, m_hat, seed * 57, backend=svd_backend)
-    for i in range(d):
-        comm.log("fl", f"dc({i})", "Z", target.Z)
+            # ---- Step 3c + 12: per-user G, collaboration representations ---
+            # All users of the protocol solved in ONE batched QR call on
+            # device, and all per-user X̂ = X̃ G products computed in ONE
+            # padded batched matmul (collab.apply_G_all) instead of a
+            # per-user host loop.
+            with obs.span("feddcl.protocol.solve_G"):
+                flat_A = [inter_A[i][j] for i in range(d)
+                          for j in range(len(Xs[i]))]
+                flat_G = collab.solve_G_all(flat_A, target.Z,
+                                            backend=svd_backend)
+            with obs.span("feddcl.protocol.apply_G"):
+                flat_X = [inter_X[i][j] for i in range(d)
+                          for j in range(len(Xs[i]))]
+                flat_XG = collab.apply_G_all(flat_X, flat_G,
+                                             backend=svd_backend)
+                Gs: List[List[np.ndarray]] = []
+                collab_X: List[np.ndarray] = []
+                collab_Y: List[np.ndarray] = []
+                k = 0
+                for i in range(d):
+                    c_i = len(Xs[i])
+                    Gs.append(flat_G[k:k + c_i])
+                    collab_X.append(np.concatenate(flat_XG[k:k + c_i],
+                                                   axis=0))
+                    collab_Y.append(np.concatenate(list(Ys[i]), axis=0))
+                    k += c_i
 
-    # ---- Step 3c + 12: per-user G, collaboration representations ----------
-    # All users of the protocol solved in ONE batched QR call on device, and
-    # all per-user X̂ = X̃ G products computed in ONE padded batched matmul
-    # (collab.apply_G_all) instead of a per-user host loop.
-    flat_A = [inter_A[i][j] for i in range(d) for j in range(len(Xs[i]))]
-    flat_G = collab.solve_G_all(flat_A, target.Z, backend=svd_backend)
-    flat_X = [inter_X[i][j] for i in range(d) for j in range(len(Xs[i]))]
-    flat_XG = collab.apply_G_all(flat_X, flat_G, backend=svd_backend)
-    Gs: List[List[np.ndarray]] = []
-    collab_X: List[np.ndarray] = []
-    collab_Y: List[np.ndarray] = []
-    k = 0
-    for i in range(d):
-        c_i = len(Xs[i])
-        Gs.append(flat_G[k:k + c_i])
-        collab_X.append(np.concatenate(flat_XG[k:k + c_i], axis=0))
-        collab_Y.append(np.concatenate(list(Ys[i]), axis=0))
-        k += c_i
-
-    state = None
-    if onboard:
-        be = collab.get_backend(svd_backend)
-        stacked = [np.concatenate(row, axis=1) for row in inter_A]
-        state = OnboardState(
-            seed=seed, m_tilde=m_tilde, m_hat=m_hat,
-            mapping_kind=mapping_kind, backend=svd_backend,
-            inter_A=[list(row) for row in inter_A],
-            inter_X=[list(row) for row in inter_X],
-            grams=[be.gram(A) for A in stacked],
-            bases_B=[gb.B for gb in bases],
-            g_factors=[be.factor_G_many(row) for row in inter_A])
+        state = None
+        if onboard:
+            with obs.span("feddcl.protocol.onboard_state"):
+                be = collab.get_backend(svd_backend)
+                stacked = [np.concatenate(row, axis=1) for row in inter_A]
+                state = OnboardState(
+                    seed=seed, m_tilde=m_tilde, m_hat=m_hat,
+                    mapping_kind=mapping_kind, backend=svd_backend,
+                    inter_A=[list(row) for row in inter_A],
+                    inter_X=[list(row) for row in inter_X],
+                    grams=[be.gram(A) for A in stacked],
+                    bases_B=[gb.B for gb in bases],
+                    g_factors=[be.factor_G_many(row) for row in inter_A])
 
     return FedDCLSetup(anchor=anchor, mappings=mappings, Gs=Gs,
                        collab_X=collab_X, collab_Y=collab_Y, comm=comm,

@@ -34,11 +34,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.federated import (PlanCache, _tree_signature, bucket_pow2,
                                   default_plan_cache)
 from repro.core.protocol import FedDCLSetup
 from repro.models import mlp
 from repro.serve_collab.tables import TenantTable, build_tables
+
+
+def _fetch(x) -> np.ndarray:
+    """A device array read to the host (counted as
+    ``feddcl.device.fetches``)."""
+    obs.count("feddcl.device.fetches")
+    return np.asarray(x)
 
 
 @dataclass
@@ -174,48 +182,53 @@ class ServeCollab:
         Returns rows served (0 when idle)."""
         if not self.queue:
             return 0
-        g = self.queue[0].group
-        tbl = self.tables[g]
-        batch: List[tuple] = []                    # (req, lo, take)
-        rows = 0
-        for req in self.queue:
-            if req.group != g:
-                continue
-            take = min(req.rows - req.served, self.max_batch - rows)
-            if take <= 0:
-                continue
-            batch.append((req, req.served, take))
-            rows += take
-            if rows >= self.max_batch:
-                break
-        b_pad = self.bucket(rows)
-        x = np.zeros((b_pad, tbl.in_dim), np.float32)
-        tix = np.zeros((b_pad,), np.int32)
-        at = 0
-        for req, lo, take in batch:
-            x[at:at + take] = req.x[lo:lo + take]
-            tix[at:at + take] = req.user
-            at += take
-        fn = self._step_fn(tbl.t_pad, b_pad, tbl.in_dim, tbl.out_dim)
-        y = np.asarray(fn(self.params, tbl.M, tbl.mu, x, tix))
-        at = 0
-        now = time.perf_counter()
-        for req, lo, take in batch:
-            if req.out is None:
-                req.out = np.zeros((req.rows, y.shape[1]), np.float32)
-            req.out[lo:lo + take] = y[at:at + take]
-            at += take
-            req.served += take
-            req.status = "truncated"               # partially served so far
-            if req.served == req.rows:
-                req.status = "done"
-                req.t_done = now
-                self.latencies.append(req.latency)
-                self.requests_done += 1
-        self.queue = deque(r for r in self.queue if r.served < r.rows)
-        self.steps += 1
-        self.rows_served += rows
-        self.bucket_hist[(g, tbl.t_pad, b_pad)] += 1
+        with obs.span("feddcl.serve.step"):
+            with obs.span("feddcl.serve.pack"):
+                g = self.queue[0].group
+                tbl = self.tables[g]
+                batch: List[tuple] = []                # (req, lo, take)
+                rows = 0
+                for req in self.queue:
+                    if req.group != g:
+                        continue
+                    take = min(req.rows - req.served, self.max_batch - rows)
+                    if take <= 0:
+                        continue
+                    batch.append((req, req.served, take))
+                    rows += take
+                    if rows >= self.max_batch:
+                        break
+                b_pad = self.bucket(rows)
+                x = np.zeros((b_pad, tbl.in_dim), np.float32)
+                tix = np.zeros((b_pad,), np.int32)
+                at = 0
+                for req, lo, take in batch:
+                    x[at:at + take] = req.x[lo:lo + take]
+                    tix[at:at + take] = req.user
+                    at += take
+            with obs.span("feddcl.serve.dispatch"):
+                fn = self._step_fn(tbl.t_pad, b_pad, tbl.in_dim, tbl.out_dim)
+                y = _fetch(fn(self.params, tbl.M, tbl.mu, x, tix))
+            with obs.span("feddcl.serve.scatter"):
+                at = 0
+                now = time.perf_counter()
+                for req, lo, take in batch:
+                    if req.out is None:
+                        req.out = np.zeros((req.rows, y.shape[1]), np.float32)
+                    req.out[lo:lo + take] = y[at:at + take]
+                    at += take
+                    req.served += take
+                    req.status = "truncated"       # partially served so far
+                    if req.served == req.rows:
+                        req.status = "done"
+                        req.t_done = now
+                        self.latencies.append(req.latency)
+                        self.requests_done += 1
+                self.queue = deque(r for r in self.queue
+                                   if r.served < r.rows)
+                self.steps += 1
+                self.rows_served += rows
+                self.bucket_hist[(g, tbl.t_pad, b_pad)] += 1
         return rows
 
     def serve(self, requests: Optional[Sequence[CollabRequest]] = None, *,
@@ -244,7 +257,8 @@ class ServeCollab:
         """Rebuild every group's table from the (refreshed) setup: Z moved,
         so every tenant's combined map changed — table CONTENT is runtime
         data, only a grown pow2 tenant pad can introduce a new bucket."""
-        self.tables = build_tables(self.setup, self.bucket)
+        with obs.span("feddcl.serve.refresh_tables"):
+            self.tables = build_tables(self.setup, self.bucket)
 
     def onboard_user(self, i: int, X_new: np.ndarray,
                      Y_new: np.ndarray) -> int:

@@ -193,6 +193,21 @@ def test_r006_flags_oversized_clamp():
     assert "deflates" in vs[0].message
 
 
+@pytest.mark.parametrize("call", ["jax.device_get(plan(*args))",
+                                  "_fetch(plan(*args))"])
+def test_r008_flags_each_host_read_in_a_round_loop(call):
+    """A module's counted `_fetch` helper is a host sync like device_get."""
+    vs = _lint(f"""
+    import jax
+
+    def drive(plan, args, rounds):
+        for rnd in range(rounds):
+            host = {call}
+        return host
+    """)
+    assert [v.rule for v in vs] == ["R008"], [v.format() for v in vs]
+
+
 def test_syntax_error_reported_not_raised():
     vs = lint_source("def broken(:\n")
     assert len(vs) == 1 and vs[0].rule == "E000"

@@ -131,9 +131,8 @@ def test_streamed_eval_history_matches_stacked(eval_chunk):
         batch_size=padded.batch_size, opt=adamw(1e-2), batch_loss=batch_loss,
         rounds=6, local_epochs=2, collect="stack", masked=padded.has_padding)
     legacy = federated._run_scan(
-        batch_loss, params, padded, opt=adamw(1e-2), rounds=6, local_epochs=2,
-        aggregator="fedavg", seed=7, eval_fn=ev, per_example=True,
-        reset_opt=True, plan=stacked_plan)
+        stacked_plan, params, federated._plan_args(padded, 7, 6), rounds=6,
+        eval_fn=ev)
     assert len(scan.history) == len(legacy.history) == 6
     for s, l, h in zip(scan.history, legacy.history, host.history):
         assert abs(s["w0"] - l["w0"]) < 1e-6
