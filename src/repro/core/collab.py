@@ -13,13 +13,15 @@ when that product is singular/non-square.
 
 Backends (`CollabBackend`, DESIGN.md §3):
   "host"   — NumPy float64 LAPACK, faithful to the paper's MATLAB; serial
-             per-group SVDs and per-user `lstsq` calls.
-  "device" — device-resident batched engine: all groups go through ONE
-             batched fp32 Gram reduction + batched eigh (Pallas `gram`
-             kernel on TPU), and all users of the protocol go through ONE
-             jitted batched QR least-squares (`solve_G_batched`). Ragged
-             group/user widths are zero-padded to the max width.
+             per-group SVDs and per-user QR least squares.
+  "device" — device-resident batched engine: the groups of each width go
+             through ONE batched fp32 Gram reduction + batched eigh (Pallas
+             `gram` kernel on TPU), and all users of the protocol through
+             ONE jitted batched QR factoring and triangular solve. Ragged
+             user widths are zero-padded to the max width.
   "tpu"    — alias of "device" (legacy name).
+Both hand back each group's Gram and each user's QR factors beside their
+answers: the onboarding state keeps them, at no second pass.
 
 The obfuscation matrices C_1/C_2 are tiny (m̂ × m̂) and stay on host in both
 backends so the two paths share identical RNG streams; because B̃ = U C_1
@@ -100,11 +102,9 @@ class HostBackend:
         return _fix_signs(U[:, :k], s[:k], Vt[:k].T)
 
     def topk_svd_many(self, mats: Sequence[np.ndarray], k: int):
-        return [self.topk_svd(A, k) for A in mats]
-
-    def solve_G_many(self, anchors: Sequence[np.ndarray],
-                     Z: np.ndarray) -> List[np.ndarray]:
-        return [solve_G(A, Z) for A in anchors]
+        """(svds, grams): each matrix's rank-k SVD and its `gram`."""
+        return [self.topk_svd(A, k) for A in mats], [self.gram(A)
+                                                     for A in mats]
 
     def apply_G_many(self, Xs: Sequence[np.ndarray],
                      Gs: Sequence[np.ndarray]) -> List[np.ndarray]:
@@ -147,6 +147,12 @@ class HostBackend:
     def factor_G_append(self, factors, a_new: np.ndarray):
         return list(factors) + [np.linalg.qr(np.asarray(a_new, np.float64))]
 
+    def split_factors(self, factors, counts: Sequence[int]) -> list:
+        """A flat roster's factors cut into consecutive runs of `counts`
+        users, one run per group."""
+        ends = np.cumsum(counts)
+        return [factors[e - c:e] for c, e in zip(counts, ends)]
+
     def solve_G_factors(self, factors, Z: np.ndarray) -> List[np.ndarray]:
         """Eq. (3) for every user from cached factors: one triangular solve
         per user against the refreshed target, zero re-factorizations."""
@@ -155,23 +161,29 @@ class HostBackend:
 
 
 class DeviceBackend:
-    """Jitted batched path: one Gram+eigh launch for all groups, one QR
+    """Jitted batched path: one Gram+eigh launch per group width, one QR
     solve for all users. fp32 on-device; outputs returned as NumPy."""
 
     name = "device"
 
     def __init__(self, ridge: float = 0.0):
-        # relative Tikhonov strength for solve_G_batched; 0.0 keeps exact
+        # relative Tikhonov strength of the QR factoring; 0.0 keeps exact
         # lstsq agreement and requires full-column-rank anchors (the
         # protocol's generic case) — pass e.g. 1e-3 via
         # get_backend(collab.DeviceBackend(ridge=...)) for degenerate data
         self.ridge = float(ridge)
 
     def topk_svd(self, A: np.ndarray, k: int):
-        return self.topk_svd_many([np.asarray(A)], k)[0]
+        import jax.numpy as jnp
+        from repro.kernels.gram import ops as gram_ops
+        # clamp exactly like HostBackend.topk_svd (min(k, *A.shape))
+        U, s, V = (_fetch(x)[0] for x in gram_ops.gram_eigh_topk_batched(
+            jnp.asarray(A, jnp.float32)[None], int(min(k, *A.shape))))
+        return _fix_signs(U, s, V)
 
     def topk_svd_many(self, mats: Sequence[np.ndarray], k: int):
-        """One batched Gram+eigh launch per distinct matrix width. Zero
+        """(svds, grams): each matrix's rank-k SVD and its fp32 Gram, from
+        one batched Gram reduction and eigh per distinct width. Zero
         columns would leave the exact top-k untouched but change the f32
         eigh's roundoff (3.4e-5 on the eigenvectors on a v5e), so a matrix
         is never padded: its factors do not depend on which other matrices
@@ -181,35 +193,20 @@ class DeviceBackend:
         by_width: dict = {}
         for b, m in enumerate(mats):
             by_width.setdefault(m.shape[1], []).append(b)
-        out: list = [None] * len(mats)
+        svds: list = [None] * len(mats)
+        grams: list = [None] * len(mats)
         for idx in by_width.values():
-            stack = np.stack([np.asarray(mats[b], np.float32) for b in idx])
-            # clamp exactly like HostBackend.topk_svd (min(k, *A.shape))
+            stack = jnp.asarray(np.stack([np.asarray(mats[b], np.float32)
+                                          for b in idx]))
             k_b = int(min(k, *stack.shape[1:]))
-            U, s, V = (_fetch(x) for x in gram_ops.gram_eigh_topk_batched(
-                jnp.asarray(stack), k_b))
+            g = gram_ops.gram_batched(stack)
+            U, s, V = (_fetch(x) for x in
+                       gram_ops.eigh_topk_recover_batched(g, stack, k_b))
+            g = _fetch(g)
             for pos, b in enumerate(idx):
-                out[b] = _fix_signs(U[pos], s[pos], V[pos])
-        return out
-
-    def solve_G_many(self, anchors: Sequence[np.ndarray],
-                     Z: np.ndarray) -> List[np.ndarray]:
-        import jax.numpy as jnp
-        from repro.kernels.gram import ops as gram_ops
-        padded, mask = pad_ragged(anchors)
-        G = gram_ops.solve_G_batched(jnp.asarray(padded),
-                                     jnp.asarray(Z, jnp.float32),
-                                     jnp.asarray(mask), ridge=self.ridge)
-        G = _fetch(G)
-        if not np.all(np.isfinite(G)):
-            bad = [b for b in range(len(anchors))
-                   if not np.all(np.isfinite(G[b]))]
-            raise FloatingPointError(
-                f"device least-squares produced non-finite G for users {bad}: "
-                "anchor columns are (near-)collinear, which the QR path "
-                "cannot handle at ridge=0 — use collab.DeviceBackend("
-                "ridge=1e-3) as svd_backend, or svd_backend='host'")
-        return [G[b, : a.shape[1]] for b, a in enumerate(anchors)]
+                svds[b] = _fix_signs(U[pos], s[pos], V[pos])
+                grams[b] = g[pos]
+        return svds, grams
 
     def apply_G_many(self, Xs: Sequence[np.ndarray],
                      Gs: Sequence[np.ndarray]) -> List[np.ndarray]:
@@ -263,7 +260,7 @@ class DeviceBackend:
 
     def factor_G_many(self, anchors: Sequence[np.ndarray]):
         """ONE batched QR factorization of the (padded) augmented anchor
-        stack — the Z-independent half of `solve_G_batched`, cached."""
+        stack — the Z-independent half of eq. (3), cached."""
         import jax.numpy as jnp
         from repro.kernels.gram import ops as gram_ops
         padded, mask = pad_ragged(anchors)
@@ -296,6 +293,19 @@ class DeviceBackend:
                 "r": factors["r"],
                 "widths": factors["widths"] + [a_new.shape[1]]}
 
+    def split_factors(self, factors, counts: Sequence[int]) -> list:
+        """A flat roster's factor stack cut into consecutive runs of
+        `counts` users, one run per group. A run keeps the roster's pad
+        width even where its group is narrower: the mask makes the padded
+        columns exact no-ops, and `factor_G_append` pads a newcomer to it."""
+        import jax.numpy as jnp
+        cuts = np.cumsum(counts)[:-1]
+        q, rr = (jnp.split(factors[key], cuts) for key in ("q", "rr"))
+        return [{"q": a, "rr": b, "mask": m, "r": factors["r"],
+                 "widths": w.tolist()} for a, b, m, w in zip(
+                     q, rr, np.split(factors["mask"], cuts),
+                     np.split(np.asarray(factors["widths"]), cuts))]
+
     def solve_G_factors(self, factors, Z: np.ndarray) -> List[np.ndarray]:
         """All users of a group re-solved against a refreshed Z in ONE
         batched triangular solve from the cached factors."""
@@ -308,8 +318,10 @@ class DeviceBackend:
             bad = [b for b in range(G.shape[0])
                    if not np.all(np.isfinite(G[b]))]
             raise FloatingPointError(
-                f"device least-squares produced non-finite G for users {bad} "
-                "from cached factors — see DeviceBackend.solve_G_many")
+                f"device least-squares produced non-finite G for users {bad}: "
+                "anchor columns are (near-)collinear, which the QR path "
+                "cannot handle at ridge=0 — use collab.DeviceBackend("
+                "ridge=1e-3) as svd_backend, or svd_backend='host'")
         return [G[b, :w] for b, w in enumerate(factors["widths"])]
 
 
@@ -389,19 +401,19 @@ def intra_group_basis(anchors: List[np.ndarray], m_hat_i: int, seed: int,
 
 
 def intra_group_bases(anchor_groups: Sequence[Sequence[np.ndarray]],
-                      m_hat: int, seeds: Sequence[int],
-                      backend: str = "host") -> List[GroupBasis]:
-    """Eq. (1) for ALL d DC servers at once. On the device backend the d
-    stacked-anchor matrices (ragged widths, zero-padded) go through a single
-    batched Gram+eigh launch; on host this is the serial per-group loop."""
+                      m_hat: int, seeds: Sequence[int], backend: str = "host"
+                      ) -> Tuple[List[GroupBasis], List[np.ndarray]]:
+    """Eq. (1) for ALL d DC servers at once: one batched Gram+eigh launch
+    per distinct width on the device backend, the serial per-group loop on
+    host. Returns the bases and each group's Gram of its stacked anchors."""
     be = get_backend(backend)
     stacked = [np.concatenate(list(g), axis=1) for g in anchor_groups]
-    svds = be.topk_svd_many(stacked, m_hat)
+    svds, grams = be.topk_svd_many(stacked, m_hat)
     return [
         _basis_from_svd(svd, np.random.default_rng(seed),
                         [a.shape[1] for a in group])
         for svd, seed, group in zip(svds, seeds, anchor_groups)
-    ]
+    ], grams
 
 
 def central_target(bases: List[GroupBasis], m_hat: int, seed: int,
@@ -421,11 +433,13 @@ def solve_G(anchor_j: np.ndarray, Z: np.ndarray) -> np.ndarray:
 
 
 def solve_G_all(anchors: Sequence[np.ndarray], Z: np.ndarray,
-                backend: str = "host") -> List[np.ndarray]:
-    """Eq. (3) for a flat list of users. The device backend pads the ragged
-    anchor widths and answers with ONE batched QR solve — zero per-user
-    `lstsq` calls."""
-    return get_backend(backend).solve_G_many(anchors, Z)
+                backend: str = "host"):
+    """Eq. (3) for a flat list of users from their QR factors (the device
+    backend: ONE batched QR of the padded anchors). Returns (G per user,
+    the factors)."""
+    be = get_backend(backend)
+    factors = be.factor_G_many(anchors)
+    return be.solve_G_factors(factors, Z), factors
 
 
 def apply_G_all(Xs: Sequence[np.ndarray], Gs: Sequence[np.ndarray],

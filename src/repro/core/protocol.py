@@ -68,12 +68,12 @@ class OnboardState:
 
       inter_A / inter_X — every user's anchor/data intermediate
           representations (step 2 never re-run for existing tenants)
-      grams     — per-group Gram of the stacked anchors, grown by blocked
-          cross-products on onboarding (collab.gram_update_blocked)
+      grams     — per-group Gram of the stacked anchors (step 3a's own),
+          grown by blocked cross-products (collab.gram_update_blocked)
       bases_B   — per-group B̃^(i); only the group that gained a tenant
           re-derives its basis (small eigh of the maintained Gram)
-      g_factors — per-group cached QR factors of every user's Ã_j: a Z
-          refresh re-solves ALL G's with triangular solves only
+      g_factors — per-group run of step 3c's QR factors of every user's
+          Ã_j: a Z refresh re-solves ALL G's with triangular solves only
     """
     seed: int
     m_tilde: int
@@ -283,8 +283,9 @@ def run_protocol(
 
     `onboard=True` additionally retains the `OnboardState` (per-user
     intermediate representations, per-group Grams, cached G factors) that
-    `FedDCLSetup.onboard_user`/`onboard_silo` need — a little extra setup
-    compute and memory, so it is opt-in (FedDCL.fit opts in)."""
+    `FedDCLSetup.onboard_user`/`onboard_silo` need: step 3's own Grams
+    and factors, kept at a cost in memory only; opt-in (FedDCL.fit opts
+    in)."""
     d = len(Xs)
     m = Xs[0][0].shape[1]
     m_hat = m_hat or m_tilde
@@ -325,10 +326,10 @@ def run_protocol(
 
         with obs.span("feddcl.protocol.step3"):
             # ---- Step 3a: intra-group bases -> central server -------------
-            # One batched Gram+eigh launch for all d groups on the device
+            # One batched Gram+eigh launch per group width on the device
             # backend; serial LAPACK loop on host.
             with obs.span("feddcl.protocol.bases"):
-                bases = collab.intra_group_bases(
+                bases, grams = collab.intra_group_bases(
                     inter_A, m_hat, seeds=[seed * 31 + i for i in range(d)],
                     backend=svd_backend)
             for i, gb in enumerate(bases):
@@ -342,15 +343,15 @@ def run_protocol(
                 comm.log("fl", f"dc({i})", "Z", target.Z)
 
             # ---- Step 3c + 12: per-user G, collaboration representations ---
-            # All users of the protocol solved in ONE batched QR call on
+            # All users of the protocol factored in ONE batched QR call on
             # device, and all per-user X̂ = X̃ G products computed in ONE
             # padded batched matmul (collab.apply_G_all) instead of a
             # per-user host loop.
             with obs.span("feddcl.protocol.solve_G"):
                 flat_A = [inter_A[i][j] for i in range(d)
                           for j in range(len(Xs[i]))]
-                flat_G = collab.solve_G_all(flat_A, target.Z,
-                                            backend=svd_backend)
+                flat_G, factors = collab.solve_G_all(flat_A, target.Z,
+                                                     backend=svd_backend)
             with obs.span("feddcl.protocol.apply_G"):
                 flat_X = [inter_X[i][j] for i in range(d)
                           for j in range(len(Xs[i]))]
@@ -372,15 +373,14 @@ def run_protocol(
         if onboard:
             with obs.span("feddcl.protocol.onboard_state"):
                 be = collab.get_backend(svd_backend)
-                stacked = [np.concatenate(row, axis=1) for row in inter_A]
                 state = OnboardState(
                     seed=seed, m_tilde=m_tilde, m_hat=m_hat,
                     mapping_kind=mapping_kind, backend=svd_backend,
                     inter_A=[list(row) for row in inter_A],
                     inter_X=[list(row) for row in inter_X],
-                    grams=[be.gram(A) for A in stacked],
-                    bases_B=[gb.B for gb in bases],
-                    g_factors=[be.factor_G_many(row) for row in inter_A])
+                    grams=grams, bases_B=[gb.B for gb in bases],
+                    g_factors=be.split_factors(
+                        factors, [len(row) for row in inter_A]))
 
     return FedDCLSetup(anchor=anchor, mappings=mappings, Gs=Gs,
                        collab_X=collab_X, collab_Y=collab_Y, comm=comm,

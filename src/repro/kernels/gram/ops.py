@@ -2,7 +2,7 @@
 
 Single-matrix entry points (`gram`, `gram_eigh_topk`) serve the legacy
 one-group-at-a-time path; the batched entry points (`gram_batched`,
-`gram_eigh_topk_batched`, `solve_G_batched`) are the device-resident
+`gram_eigh_topk_batched`, `solve_G_factor_batched`) are the device-resident
 collaboration engine: every group (or every user) is a slice of one stacked,
 zero-padded array and the whole of FedDCL step 3 runs in a handful of jitted
 calls instead of Python loops.
@@ -11,7 +11,8 @@ Padded-ragged convention (see DESIGN.md): ragged stacks are zero-padded on
 the trailing column axis up to the max width. Zero columns are harmless for
 the Gram route — AᵀA acquires zero rows/cols, eigh keeps them in the null
 space, and the top-k eigenpairs of the real block are untouched. For least
-squares they are handled explicitly via `col_mask` (see `solve_G_batched`).
+squares they are handled explicitly via `col_mask` (see
+`solve_G_factor_batched`).
 """
 from __future__ import annotations
 
@@ -151,49 +152,25 @@ def apply_G_batched(x, g):
 
 
 @jax.jit
-def solve_G_batched(a, z, col_mask=None, ridge: float = 0.0):
-    """Batched eq. (3): G_b = argmin ‖A_b G − Z_b‖_F for a whole stack of
-    users in one jitted QR solve.
+def solve_G_factor_batched(a, col_mask=None, ridge: float = 0.0):
+    """Factor half of batched eq. (3), G_b = argmin ‖A_b G − Z‖_F: the
+    reduced QR of the augmented anchor stacks, (q (B, r+m_max, m_max),
+    rr (B, m_max, m_max)); `solve_G_from_factors` is the other half.
 
     a:        (B, r, m_max) anchors, zero-padded on the column axis
-    z:        (r, m_hat) shared target, or (B, r, m_hat) per-batch targets
     col_mask: (B, m_max) with True on REAL columns (None = all real)
     ridge:    relative Tikhonov strength (see below); 0.0 = exact lstsq
-
-    Returns G (B, m_max, m_hat) with exact zero rows at padded positions.
 
     Padded columns would make the QR factor singular, so the system is
     augmented with m_max extra rows holding diag(1 − mask): the objective
     becomes ‖A_real G_real − Z‖² + Σ_padded G_k², whose minimiser is the
-    plain least-squares solution on real columns and 0 on padded rows
-    (cross terms vanish because padded columns of A are exactly zero).
-    Unlike normal equations this does not square the condition number.
+    plain least-squares solution on real columns and 0 on padded rows.
+    QR without pivoting needs the REAL columns full rank (the protocol's
+    generic case); for exactly collinear columns pass ridge > 0 (e.g.
+    1e-3): the real-column rows become ridge · max_colnorm(A_b) · I.
 
-    QR without pivoting requires the REAL columns to be full rank — the
-    protocol guarantees this generically (anchors are random full-rank
-    matrices through injective maps), but exactly collinear anchor columns
-    would blow the triangular solve up where host lstsq returns the bounded
-    min-norm solution. For such degenerate inputs pass ridge > 0 (e.g.
-    1e-3): the real-column augmentation rows become
-    ridge · max_colnorm(A_b) · I, bounding ‖G‖ by ~‖Z‖/(ridge·scale) at
-    the cost of an O(ridge²·κ²) relative perturbation on well-conditioned
-    directions.
-    """
-    q, rr = solve_G_factor_batched(a, col_mask, ridge=ridge)
-    return solve_G_from_factors(q, rr, z, col_mask)
-
-
-@jax.jit
-def solve_G_factor_batched(a, col_mask=None, ridge: float = 0.0):
-    """Factor half of `solve_G_batched`: the batched reduced QR of the
-    augmented anchor stacks. Returns (q (B, r+m_max, m_max),
-    rr (B, m_max, m_max)).
-
-    The factors depend only on the anchors, never on the target Z — the
-    incremental-onboarding path caches them per tenant so a Z refresh
-    (a new tenant shifted the central target) re-solves every G with
-    `solve_G_from_factors` alone: one triangular solve per tenant, zero
-    re-factorizations.
+    The factors never depend on Z: onboarding caches them, so a Z refresh
+    re-solves every G with triangular solves alone.
     """
     a = a.astype(jnp.float32)
     b, r, m_max = a.shape
@@ -209,8 +186,9 @@ def solve_G_factor_batched(a, col_mask=None, ridge: float = 0.0):
 
 @jax.jit
 def solve_G_from_factors(q, rr, z, col_mask=None):
-    """Apply half of `solve_G_batched`: G = R⁻¹ Qᵀ [Z; 0] from cached QR
-    factors. z: (r, m_hat) shared target or (B, r, m_hat) per-batch."""
+    """Apply half of batched eq. (3): G = R⁻¹ Qᵀ [Z; 0] from cached QR
+    factors, exact zero rows at padded positions. z: (r, m_hat) shared
+    target or (B, r, m_hat) per-batch."""
     b, _, m_max = rr.shape
     if z.ndim == 2:
         z = jnp.broadcast_to(z[None], (b,) + z.shape)

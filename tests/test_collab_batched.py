@@ -81,8 +81,14 @@ def test_gram_eigh_topk_batched_zero_padded_columns():
 
 
 # --------------------------------------------------------------------------
-# solve_G_batched vs np.linalg.lstsq over ragged widths
+# batched eq. (3) (QR factors, then the triangular solve) vs np.linalg.lstsq
+# over ragged widths
 # --------------------------------------------------------------------------
+
+def _solve_G_batched(a, z, col_mask=None, ridge=0.0):
+    q, rr = gram_ops.solve_G_factor_batched(a, col_mask, ridge=ridge)
+    return gram_ops.solve_G_from_factors(q, rr, z, col_mask)
+
 
 @pytest.mark.parametrize("widths", [[6], [6, 3, 5, 2], [8, 8, 8],
                                     [1, 12, 4, 7, 2, 9]])
@@ -92,9 +98,8 @@ def test_solve_G_batched_matches_lstsq_ragged(widths):
     Z = rng.standard_normal((r, m_hat)).astype(np.float32)
     mats = [rng.standard_normal((r, w)).astype(np.float32) for w in widths]
     padded, mask = collab.pad_ragged(mats)
-    G = np.asarray(gram_ops.solve_G_batched(jnp.asarray(padded),
-                                            jnp.asarray(Z),
-                                            jnp.asarray(mask)))
+    G = np.asarray(_solve_G_batched(jnp.asarray(padded), jnp.asarray(Z),
+                                    jnp.asarray(mask)))
     for b, (A, w) in enumerate(zip(mats, widths)):
         G_ref, *_ = np.linalg.lstsq(A, Z, rcond=None)
         np.testing.assert_allclose(G[b, :w], G_ref, atol=2e-3, rtol=2e-3)
@@ -105,7 +110,7 @@ def test_solve_G_batched_per_batch_targets():
     rng = np.random.default_rng(6)
     A = rng.standard_normal((3, 100, 8)).astype(np.float32)
     Z = rng.standard_normal((3, 100, 4)).astype(np.float32)
-    G = np.asarray(gram_ops.solve_G_batched(jnp.asarray(A), jnp.asarray(Z)))
+    G = np.asarray(_solve_G_batched(jnp.asarray(A), jnp.asarray(Z)))
     for b in range(3):
         G_ref, *_ = np.linalg.lstsq(A[b], Z[b], rcond=None)
         np.testing.assert_allclose(G[b], G_ref, atol=2e-3, rtol=2e-3)
@@ -115,8 +120,8 @@ def test_solve_G_all_device_matches_host():
     rng = np.random.default_rng(7)
     anchors = [rng.standard_normal((300, w)) for w in (5, 9, 3)]
     Z = rng.standard_normal((300, 4))
-    G_host = collab.solve_G_all(anchors, Z, backend="host")
-    G_dev = collab.solve_G_all(anchors, Z, backend="device")
+    G_host, _ = collab.solve_G_all(anchors, Z, backend="host")
+    G_dev, _ = collab.solve_G_all(anchors, Z, backend="device")
     for gh, gd in zip(G_host, G_dev):
         assert gh.shape == gd.shape
         np.testing.assert_allclose(gd, gh, atol=2e-3, rtol=2e-3)
@@ -182,8 +187,10 @@ def test_device_path_makes_zero_lstsq_calls(partitions, monkeypatch):
     run_protocol(Xs, Ys, m_tilde=4, anchor_r=600, seed=0,
                  svd_backend="device")
     assert calls == [], f"device path made {len(calls)} lstsq calls"
-    run_protocol(Xs, Ys, m_tilde=4, anchor_r=600, seed=0, svd_backend="host")
-    assert len(calls) == 4, "host path should lstsq once per user"
+    # the counter sees the host reference's one lstsq (both backends now
+    # answer eq. (3) from QR factors, which onboarding keeps)
+    collab.solve_G(Xs[0][0], Xs[0][0][:, :1])
+    assert len(calls) == 1, "the counter should see one lstsq"
 
 
 def test_topk_svd_many_ragged_widths_match_host_clamp():
@@ -193,10 +200,10 @@ def test_topk_svd_many_ragged_widths_match_host_clamp():
     groups = [[rng.standard_normal((200, 8))],
               [rng.standard_normal((200, 16)), rng.standard_normal((200, 16))]]
     for m_hat in (4, 16):
-        host = collab.intra_group_bases(groups, m_hat, seeds=[0, 1],
-                                        backend="host")
-        dev = collab.intra_group_bases(groups, m_hat, seeds=[0, 1],
-                                       backend="device")
+        host, _ = collab.intra_group_bases(groups, m_hat, seeds=[0, 1],
+                                           backend="host")
+        dev, _ = collab.intra_group_bases(groups, m_hat, seeds=[0, 1],
+                                          backend="device")
         assert [b.B.shape for b in host] == [b.B.shape for b in dev]
         for bh, bd in zip(host, dev):
             rel = np.linalg.norm(bh.B - bd.B) / np.linalg.norm(bh.B)
@@ -210,8 +217,8 @@ def test_solve_G_batched_ridge_bounds_rank_deficient():
     A = rng.standard_normal((200, 6)).astype(np.float32)
     A[:, 3] = A[:, 2]                       # exactly collinear pair
     Z = rng.standard_normal((200, 4)).astype(np.float32)
-    G = np.asarray(gram_ops.solve_G_batched(jnp.asarray(A[None]),
-                                            jnp.asarray(Z), ridge=1e-3))[0]
+    G = np.asarray(_solve_G_batched(jnp.asarray(A[None]),
+                                    jnp.asarray(Z), ridge=1e-3))[0]
     assert np.all(np.isfinite(G))
     assert np.abs(G).max() < 1e3
     # residual still ~ least-squares quality
@@ -221,8 +228,8 @@ def test_solve_G_batched_ridge_bounds_rank_deficient():
     assert res < res_ls * 1.01
     # and ridge leaves well-conditioned solves essentially unchanged
     B = rng.standard_normal((200, 6)).astype(np.float32)
-    G_r = np.asarray(gram_ops.solve_G_batched(jnp.asarray(B[None]),
-                                              jnp.asarray(Z), ridge=1e-3))[0]
+    G_r = np.asarray(_solve_G_batched(jnp.asarray(B[None]),
+                                      jnp.asarray(Z), ridge=1e-3))[0]
     G_0, *_ = np.linalg.lstsq(B, Z, rcond=None)
     np.testing.assert_allclose(G_r, G_0, atol=5e-3, rtol=5e-3)
 

@@ -57,8 +57,10 @@ TREES = {
 }
 # host reads of each path: D groups of equal width
 PINNED_FETCHES = {
-    # bases (U, s, V of one batched eigh), central (3), G, X̂, D Grams
-    "protocol": 3 + 3 + 1 + 1 + D,
+    # bases (U, s, V of one batched eigh), central (3), G, X̂, and the
+    # Gram stack the bases reduced, one read per distinct group width (the
+    # onboarding state keeps it: no Gram is reduced or read twice)
+    "protocol": 3 + 3 + 1 + 1 + 1,
     # blocked Gram, basis (3), central (3), one G solve per group, X̂
     "onboard": 1 + 3 + 3 + D + 1,
     "fl": 1,                                         # the round losses

@@ -14,7 +14,7 @@ near-exact), 1e-5 for the device backend (fp32 Gram/eigh/QR arithmetic).
 import numpy as np
 import pytest
 
-from repro.core import protocol
+from repro.core import collab, protocol
 
 BACKENDS = [("host", 1e-8), ("device", 1e-5)]
 
@@ -117,6 +117,35 @@ def test_repeated_onboarding_does_not_drift(backend, tol):
 
     ref = protocol.run_protocol(Xs2, Ys2, anchor=setup.anchor, **kw)
     _assert_setups_match(setup, ref, tol)
+
+
+@pytest.mark.parametrize("backend,tol", BACKENDS)
+@pytest.mark.parametrize("counts", [[2, 2, 2], [3, 1, 2]],
+                         ids=["equal_widths", "ragged_widths"])
+def test_onboard_state_equals_recomputed_state(backend, tol, counts):
+    """The state run_protocol(onboard=True) keeps is step 3's own Grams
+    and QR factors; it equals the state recomputed group by group: each
+    group's Gram reduced alone, and its users' G solved from factors of
+    the group alone (a ragged roster's runs carry the roster's pad
+    width, which the mask makes a no-op)."""
+    rng = np.random.default_rng(sum(counts) * len(counts))
+    Xs, Ys = _mkdata(rng, counts, 7)
+    setup = protocol.run_protocol(Xs, Ys, onboard=True, m_tilde=4,
+                                  anchor_r=120, seed=5, svd_backend=backend)
+    st, be = setup.onboard, collab.get_backend(backend)
+    assert len(st.grams) == len(st.g_factors) == len(counts)
+    for i, row in enumerate(st.inter_A):
+        want = be.gram(np.concatenate(row, axis=1))
+        assert st.grams[i].shape == want.shape
+        assert st.grams[i].dtype == want.dtype
+        np.testing.assert_array_equal(st.grams[i], want)
+        kept = be.solve_G_factors(st.g_factors[i], setup.Z)
+        alone = be.solve_G_factors(be.factor_G_many(row), setup.Z)
+        assert len(kept) == len(alone) == counts[i]
+        for g_kept, g_alone in zip(kept, alone):
+            assert g_kept.shape == g_alone.shape
+            s = max(1.0, float(np.abs(g_alone).max()))
+            assert np.abs(g_kept - g_alone).max() / s < tol, i
 
 
 def test_onboard_requires_state():

@@ -68,12 +68,12 @@ def test_collab_layer_theorem1_direct(backend):
     groups = [[anchor @ F @ (rng.standard_normal((m_tilde, m_tilde)) +
                              np.eye(m_tilde) * m_tilde)
                for _ in range(c)] for _ in range(d)]
-    bases = collab.intra_group_bases(groups, m_tilde,
-                                     seeds=[7 * i for i in range(d)],
-                                     backend=backend)
+    bases, _ = collab.intra_group_bases(groups, m_tilde,
+                                        seeds=[7 * i for i in range(d)],
+                                        backend=backend)
     target = collab.central_target(bases, m_tilde, seed=99, backend=backend)
     flat = [a for g in groups for a in g]
-    Gs = collab.solve_G_all(flat, target.Z, backend=backend)
+    Gs, _ = collab.solve_G_all(flat, target.Z, backend=backend)
     tol = 1e-6 if backend == "host" else 1e-3
     for A, G in zip(flat, Gs):
         assert collab.alignment_residual(A, G, target.Z) < tol
