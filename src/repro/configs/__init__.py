@@ -26,6 +26,7 @@ from repro.configs import (  # noqa: E402
     glm4_9b,
     granite_moe_1b,
     llama3_2_1b,
+    moonlight_16b_a3b,
     musicgen_large,
     rwkv6_3b,
     starcoder2_15b,
@@ -43,6 +44,7 @@ ARCHS = {
     "glm4-9b": glm4_9b.CONFIG,
     "zamba2-1.2b": zamba2_1_2b.CONFIG,
     "chameleon-34b": chameleon_34b.CONFIG,
+    "moonlight-16b-a3b": moonlight_16b_a3b.CONFIG,
 }
 
 
@@ -72,12 +74,15 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
             top_k=2,
             d_ff_expert=128,
             d_ff_shared=128 if cfg.moe.num_shared_experts else 0,
-            impl="dense",
+            # the dropless path is exact at any size and runs as it is; the
+            # capacity paths would drop rows at 4 experts, so run dense
+            impl="dropless" if cfg.moe.impl == "dropless" else "dense",
+            held_experts=0, first_held=0,
         )
     if cfg.mla is not None:
         kw["mla"] = MLAConfig(
-            q_lora_rank=64, kv_lora_rank=32, qk_nope_head_dim=32,
-            qk_rope_head_dim=16, v_head_dim=32,
+            q_lora_rank=64 if cfg.mla.q_lora_rank else 0, kv_lora_rank=32,
+            qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
         )
         kw["head_dim"] = 32
     if cfg.ssm is not None:

@@ -26,14 +26,37 @@ class MoEConfig:
     router_aux_coef: float = 0.01     # load-balance loss coefficient
     routed_scaling: float = 1.0       # deepseek-v3 routed expert scaling
     # Expert-parallel implementation: "dense" (tiny smoke configs only),
-    # "gspmd" (scatter-based dispatch, auto-partitioned), or
-    # "ep" (shard_map all_to_all expert parallelism over the model axis).
+    # "gspmd" (scatter-based dispatch, auto-partitioned), "ep" (shard_map
+    # all_to_all expert parallelism over the model axis) -- these three keep
+    # a capacity and drop the rows above it -- or "dropless" (rows sorted by
+    # expert through grouped matmuls over the held experts, none dropped).
     impl: str = "gspmd"
+    # The expert-parallel share this device holds: `held_experts`
+    # consecutive experts from `first_held` (0 = all of them). The router
+    # still scores all `num_experts`; only the dropless path holds a share.
+    held_experts: int = 0
+    first_held: int = 0
+
+    def __post_init__(self):
+        held = self.held_experts or self.num_experts
+        if self.first_held < 0 or self.first_held + held > self.num_experts:
+            raise ValueError(f"experts [{self.first_held}, "
+                             f"{self.first_held + held}) are not among "
+                             f"{self.num_experts}")
+        if held < self.num_experts and self.impl != "dropless":
+            raise ValueError("only the dropless path holds a share of the "
+                             f"experts, not impl={self.impl!r}")
+
+    @property
+    def num_held(self) -> int:
+        return self.held_experts or self.num_experts
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek multi-head latent attention."""
+    """DeepSeek multi-head latent attention. ``q_lora_rank`` 0 projects
+    queries directly (``w_q``, d × H × (nope + rope)), as Moonlight does;
+    otherwise through the low-rank ``w_dq`` → ``q_norm`` → ``w_uq``."""
 
     q_lora_rank: int
     kv_lora_rank: int

@@ -27,6 +27,9 @@ from repro.shardingx.policy import batch_spec, param_specs, to_shardings
 # shard (deepseek-v3: 671B bf16 / 16 shards = 84 GB ≫ HBM -> FSDP too).
 DECODE_FSDP_BYTES = 12e9
 
+# the train step's metrics an MoE config adds (backbone.loss_fn)
+MOE_METRICS = {"moe_aux": 0., "expert_rows": 0., "expert_rows_max": 0.}
+
 
 @dataclass
 class LoweringPlan:
@@ -166,7 +169,7 @@ def _train_plan(cfg, shape, mesh, tc, use_pallas) -> LoweringPlan:
                   P(*(tuple(bspec)[:1] + (None,) * (v.ndim - 1))))
               for k, v in bshapes.items()}
     mspecs = jax.tree.map(lambda _: P(), {"loss": 0., "ce": 0., "grad_norm": 0.,
-                                          **({"moe_aux": 0.} if cfg.moe else {}),
+                                          **(MOE_METRICS if cfg.moe else {}),
                                           **({"mtp": 0.} if cfg.mtp_depth else {})})
     return LoweringPlan(
         name=f"{cfg.name}:{shape.name}:train",
@@ -209,7 +212,7 @@ def _fed_local_plan(cfg, shape, mesh, tc, use_pallas) -> LoweringPlan:
               for k, v in bshapes.items()}
     mspecs = jax.tree.map(lambda _: P(silo_ax),
                           {"loss": 0., "ce": 0., "grad_norm": 0.,
-                           **({"moe_aux": 0.} if cfg.moe else {}),
+                           **(MOE_METRICS if cfg.moe else {}),
                            **({"mtp": 0.} if cfg.mtp_depth else {})})
     return LoweringPlan(
         name=f"{cfg.name}:{shape.name}:feddcl-local(d={d})",

@@ -22,14 +22,52 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import store
 from repro.configs import ARCHS, REDUCED
-from repro.configs.base import FederatedConfig, InputShape, TrainConfig
-from repro.core.federated import silo_replicate
+from repro.configs.base import (FederatedConfig, InputShape, ModelConfig,
+                                TrainConfig)
+from repro.core.federated import _fetch, silo_replicate
 from repro.data.tokens import TokenStream, silo_batches
 from repro.launch import steps as steps_lib
 from repro.models import backbone as bb
 from repro.models.modality import synthetic_prefix
+
+# what a round's host read brings back: per local step (and silo)
+ROUND_READ = ("loss", "grad_norm", "expert_rows", "expert_rows_max")
+
+
+def compile_round(cfg: ModelConfig, tc: TrainConfig):
+    """One FedDCL round (H silo-local steps, then the boundary) as one
+    jitted program that donates the silo params and optimizer state."""
+    round_step, opt = steps_lib.make_federated_round_step(cfg, tc)
+    return jax.jit(round_step, donate_argnums=(0, 1)), opt
+
+
+def run_rounds(step, sp, so, batches_of, n: int, *, first: int = 0):
+    """Run `n` compiled dispatches of FedDCL rounds back to back, from
+    round `first`. For each: build its batches on the host and upload them
+    (span ``feddcl.llm.prepare``); dispatch it and read back, in one host
+    read, its per-step losses, gradient norms and rows of the held experts
+    (span ``feddcl.llm.round``), which also add to the counters
+    ``feddcl.llm.tokens``, ``.expert_rows`` and ``.expert_rows_max``.
+
+    step: (sp, so, batches) -> (sp, so, metrics); batches_of(r) -> the
+    batch pytree of round r. Returns (sp, so, [what each read brought])."""
+    got = []
+    for r in range(first, first + n):
+        with obs.span("feddcl.llm.prepare"):
+            batches = jax.device_put(batches_of(r))
+        with obs.span("feddcl.llm.round"):
+            sp, so, ms = step(sp, so, batches)
+            m = _fetch({k: ms[k] for k in ROUND_READ if k in ms})
+        obs.count("feddcl.llm.tokens", batches["tokens"].size)
+        if "expert_rows" in m:
+            obs.count("feddcl.llm.expert_rows", int(np.sum(m["expert_rows"])))
+            obs.count("feddcl.llm.expert_rows_max",
+                      int(np.sum(m["expert_rows_max"])))
+        got.append(m)
+    return sp, so, got
 
 
 def train(arch: str, *, reduced: bool = True, steps: int = 100, batch: int = 8,
@@ -61,8 +99,7 @@ def train(arch: str, *, reduced: bool = True, steps: int = 100, batch: int = 8,
         # One FedDCL round (H vmapped silo-local steps + the fedavg_sync
         # boundary) is ONE compiled dispatch — the launch-tier consumption
         # of the core.federated scan engine (DESIGN.md §4).
-        round_step, opt = steps_lib.make_federated_round_step(cfg, tc)
-        round_step = jax.jit(round_step, donate_argnums=(0, 1))
+        round_step, opt = compile_round(cfg, tc)
         assert batch % silos == 0
         sp = silo_replicate(params, silos)
         so = jax.vmap(opt.init)(sp)
@@ -73,8 +110,7 @@ def train(arch: str, *, reduced: bool = True, steps: int = 100, batch: int = 8,
             nbs = [silo_batches(cfg.vocab_size, seq, batch // silos, silos,
                                 step0 + i, seed=seed, non_iid=non_iid)
                    for i in range(h)]
-            b = {k: jnp.asarray(np.stack([nb[k] for nb in nbs]))
-                 for k in nbs[0]}
+            b = {k: np.stack([nb[k] for nb in nbs]) for k in nbs[0]}
             if prefix is not None:
                 def step_prefix(k):
                     return jax.vmap(lambda kk: prefix(kk, batch // silos))(
@@ -90,7 +126,7 @@ def train(arch: str, *, reduced: bool = True, steps: int = 100, batch: int = 8,
                 step = step0 + i
                 if step % log_every == 0 or step == steps - 1:
                     rec = {"step": step,
-                           "loss": float(jnp.mean(metrics["loss"][i])),
+                           "loss": float(np.mean(metrics["loss"][i])),
                            "elapsed_s": time.perf_counter() - t0}
                     history.append(rec)
                     print(f"step {step:5d} loss {rec['loss']:.4f} "
@@ -105,22 +141,27 @@ def train(arch: str, *, reduced: bool = True, steps: int = 100, batch: int = 8,
 
             def multiround_batches(step0, r, h):
                 bs = [stacked_batches(step0 + i * h, h) for i in range(r)]
-                return {k: jnp.stack([b[k] for b in bs]) for k in bs[0]}
+                return {k: jnp.stack([jnp.asarray(b[k]) for b in bs])
+                        for k in bs[0]}
 
         n_rounds = steps // local_steps
         rnd = 0
         while rnd < n_rounds:
             step0 = rnd * local_steps
             if rpd > 1 and n_rounds - rnd >= rpd:
-                sp, so, metrics = multi_step(
-                    sp, so, multiround_batches(step0, rpd, local_steps))
+                sp, so, (metrics,) = run_rounds(
+                    multi_step, sp, so,
+                    lambda r: multiround_batches(r * local_steps, rpd,
+                                                 local_steps), 1, first=rnd)
                 for r in range(rpd):
                     log_round(step0 + r * local_steps,
                               jax.tree.map(lambda a, r=r: a[r], metrics))
                 rnd += rpd
             else:
-                sp, so, metrics = round_step(
-                    sp, so, stacked_batches(step0, local_steps))
+                sp, so, (metrics,) = run_rounds(
+                    round_step, sp, so,
+                    lambda r: stacked_batches(r * local_steps, local_steps),
+                    1, first=rnd)
                 log_round(step0, metrics)
                 rnd += 1
         rem = steps % local_steps
@@ -129,7 +170,8 @@ def train(arch: str, *, reduced: bool = True, steps: int = 100, batch: int = 8,
             # same semantics as the old per-step loop
             phase, _ = steps_lib.make_federated_local_phase_step(cfg, tc)
             phase = jax.jit(phase, donate_argnums=(0, 1))
-            sp, so, metrics = phase(sp, so, stacked_batches(steps - rem, rem))
+            sp, so, (metrics,) = run_rounds(
+                phase, sp, so, lambda _: stacked_batches(steps - rem, rem), 1)
             log_round(steps - rem, metrics)
         params = jax.tree.map(lambda a: a[0], sp)
     else:

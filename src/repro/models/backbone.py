@@ -167,7 +167,7 @@ def _dense_block_apply(lp: Params, x, cfg: ModelConfig, *, positions,
         attn = L.apply_rmsnorm(lp["ln_post_attn"], attn, cfg.norm_eps)
     x = x + attn
     h = L.apply_rmsnorm(lp["ln_mlp"], x, cfg.norm_eps)
-    aux = jnp.zeros((), jnp.float32)
+    aux = jnp.zeros((3,), jnp.float32)      # L.apply_moe's [aux, rows, max]
     if moe_layer:
         out, aux = L.apply_moe(lp["moe"], h, cfg)
     else:
@@ -210,7 +210,7 @@ def _scan_stack(body, x, stacked, flags=None, remat: bool = True):
     if flags is None:
         flags = jnp.zeros((n,), bool)
     x, auxs = L.maybe_scan(f, x, (stacked, flags))
-    return x, jnp.sum(auxs)
+    return x, jnp.sum(auxs, axis=0)
 
 
 def embed_inputs(params: Params, tokens, cfg: ModelConfig, *,
@@ -236,12 +236,14 @@ def embed_inputs(params: Params, tokens, cfg: ModelConfig, *,
 def forward(params: Params, tokens, cfg: ModelConfig, *, prefix_embeds=None,
             use_pallas: bool = False, remat: bool = True,
             compute_dtype=jnp.bfloat16, return_logits: bool = True):
-    """-> (logits (B, T, V) fp32 | None, hidden (B, T, d), aux)."""
+    """-> (logits (B, T, V) fp32 | None, hidden (B, T, d), aux): aux holds
+    the MoE load-balance loss and, summed over the MoE layers, the rows the
+    held experts computed and the rows of each layer's busiest one."""
     x, positions, loss_mask = embed_inputs(params, tokens, cfg,
                                            prefix_embeds=prefix_embeds)
     x = x.astype(compute_dtype)
     fam = cfg.family
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = jnp.zeros((3,), jnp.float32)
 
     if fam in ("dense", "audio", "vlm", "moe"):
         if fam == "moe" and cfg.first_k_dense:
@@ -294,7 +296,10 @@ def forward(params: Params, tokens, cfg: ModelConfig, *, prefix_embeds=None,
 
     hidden = L.apply_rmsnorm(params["ln_final"], x, cfg.norm_eps)
     logits = _lm_logits(params, hidden, cfg) if return_logits else None
-    return logits, hidden, {"moe_aux": aux_total, "loss_mask": loss_mask}
+    return logits, hidden, {"moe_aux": aux_total[0],
+                            "expert_rows": aux_total[1],
+                            "expert_rows_max": aux_total[2],
+                            "loss_mask": loss_mask}
 
 
 def _lm_logits(params: Params, hidden, cfg: ModelConfig):
@@ -371,7 +376,8 @@ def loss_fn(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
     metrics = {"ce": loss}
     if cfg.moe is not None:
         loss = loss + aux_coef * aux["moe_aux"]
-        metrics["moe_aux"] = aux["moe_aux"]
+        for k in ("moe_aux", "expert_rows", "expert_rows_max"):
+            metrics[k] = aux[k]
     if cfg.mtp_depth and "mtp" in params:
         mtp_loss = _mtp_loss(params, hidden[:, P:], tokens, labels, cfg,
                              compute_dtype)
@@ -748,8 +754,10 @@ def count_params_analytic(cfg: ModelConfig, active_only: bool = False,
         if not cfg.tie_embeddings:
             total -= cfg.vocab_size * cfg.d_model
     if active_only and cfg.moe is not None:
+        # of the held experts, top_k × held / E take a token on average
         mo = cfg.moe
         n_moe = cfg.num_layers - cfg.first_k_dense
-        inactive = n_moe * 3 * cfg.d_model * mo.d_ff_expert * (mo.num_experts - mo.top_k)
-        total -= inactive
+        inactive = (n_moe * 3 * cfg.d_model * mo.d_ff_expert
+                    * (mo.num_held - mo.top_k * mo.num_held / mo.num_experts))
+        total -= int(inactive)
     return total

@@ -10,6 +10,7 @@ Dtype convention: params live in ``param_dtype``; activations are computed in
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -168,7 +169,7 @@ def multi_head_attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     return out
 
 
-QCHUNK_THRESHOLD = 4096     # q-chunk full-sequence attention above this Sq
+QCHUNK_THRESHOLD = 4096     # q-chunk full-sequence attention from this Sq up
 QCHUNK = 1024               # query-block size for the chunked XLA path
 
 # ---------------------------------------------------------------------------
@@ -214,7 +215,9 @@ def sdpa_qchunked(q, k, v, *, q_pos, k_pos, is_local, window, softcap,
                   chunk: int = QCHUNK) -> jnp.ndarray:
     """Query-block-chunked attention: never materializes the full Sq×Sk logit
     matrix (the XLA-path analogue of the Pallas flash kernel's VMEM tiling —
-    peak temp drops from O(Sq·Sk) to O(chunk·Sk) per head)."""
+    peak temp drops from O(Sq·Sk) to O(chunk·Sk) per head). Each chunk is
+    recomputed in the backward pass, so autodiff keeps no chunk's
+    probabilities either."""
     B, Sq, H, hd = q.shape
     if Sq % chunk:
         return sdpa_reference(q, k, v, q_pos=q_pos, k_pos=k_pos,
@@ -223,6 +226,7 @@ def sdpa_qchunked(q, k, v, *, q_pos, k_pos, is_local, window, softcap,
     qs = q.reshape(B, nq, chunk, H, hd).swapaxes(0, 1)        # (nq,B,c,H,hd)
     ps = q_pos.reshape(B, nq, chunk).swapaxes(0, 1)
 
+    @functools.partial(jax.checkpoint, prevent_cse=False)
     def body(_, xs):
         qc, pc = xs
         ctx = sdpa_reference(qc, k, v, q_pos=pc, k_pos=k_pos,
@@ -234,7 +238,7 @@ def sdpa_qchunked(q, k, v, *, q_pos, k_pos, is_local, window, softcap,
 
 
 def sdpa(q, k, v, *, q_pos, k_pos, is_local, window, softcap) -> jnp.ndarray:
-    if q.shape[1] > QCHUNK_THRESHOLD:
+    if q.shape[1] >= QCHUNK_THRESHOLD:
         return sdpa_qchunked(q, k, v, q_pos=q_pos, k_pos=k_pos,
                              is_local=is_local, window=window, softcap=softcap)
     return sdpa_reference(q, k, v, q_pos=q_pos, k_pos=k_pos, is_local=is_local,
@@ -349,16 +353,31 @@ def init_mla(key, cfg: ModelConfig, dtype) -> Params:
     d, H = cfg.d_model, cfg.num_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 8)
-    return {
-        "w_dq": _dense_init(ks[0], (d, m.q_lora_rank), d, dtype),
-        "q_norm": init_rmsnorm(m.q_lora_rank, dtype),
-        "w_uq": _dense_init(ks[1], (m.q_lora_rank, H, qk), m.q_lora_rank, dtype),
+    if m.q_lora_rank:
+        p = {
+            "w_dq": _dense_init(ks[0], (d, m.q_lora_rank), d, dtype),
+            "q_norm": init_rmsnorm(m.q_lora_rank, dtype),
+            "w_uq": _dense_init(ks[1], (m.q_lora_rank, H, qk), m.q_lora_rank, dtype),
+        }
+    else:
+        p = {"w_q": _dense_init(ks[0], (d, H, qk), d, dtype)}
+    return dict(p, **{
         "w_dkv": _dense_init(ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim), d, dtype),
         "kv_norm": init_rmsnorm(m.kv_lora_rank, dtype),
         "w_uk": _dense_init(ks[3], (m.kv_lora_rank, H, m.qk_nope_head_dim), m.kv_lora_rank, dtype),
         "w_uv": _dense_init(ks[4], (m.kv_lora_rank, H, m.v_head_dim), m.kv_lora_rank, dtype),
         "wo": _dense_init(ks[5], (H, m.v_head_dim, d), H * m.v_head_dim, dtype),
-    }
+    })
+
+
+def _mla_query(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """(B, S, d) -> (B, S, H, nope + rope): through the q-LoRA, or directly
+    where ``q_lora_rank`` is 0."""
+    if not cfg.mla.q_lora_rank:
+        return jnp.einsum("bsd,dhk->bshk", x, p["w_q"].astype(x.dtype))
+    cq = jnp.einsum("bsd,dr->bsr", x, p["w_dq"].astype(x.dtype))
+    cq = apply_rmsnorm(p["q_norm"], cq, cfg.norm_eps)
+    return jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"].astype(x.dtype))
 
 
 def mla_attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
@@ -369,9 +388,7 @@ def mla_attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     m = cfg.mla
     B, S, d = x.shape
     H = cfg.num_heads
-    cq = jnp.einsum("bsd,dr->bsr", x, p["w_dq"].astype(x.dtype))
-    cq = apply_rmsnorm(p["q_norm"], cq, cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"].astype(x.dtype))
+    q = _mla_query(p, x, cfg)
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
 
     ckv_full = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(x.dtype))
@@ -423,9 +440,7 @@ def mla_decode(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     B, _, d = x.shape
     H = cfg.num_heads
     C = cache_ckv.shape[1]
-    cq = jnp.einsum("bsd,dr->bsr", x, p["w_dq"].astype(x.dtype))
-    cq = apply_rmsnorm(p["q_norm"], cq, cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"].astype(x.dtype))[:, 0]  # (B,H,qk)
+    q = _mla_query(p, x, cfg)[:, 0]                              # (B,H,qk)
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
 
     ckv_full = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(x.dtype))[:, 0]
@@ -483,14 +498,16 @@ def apply_mlp(p: Params, x: jnp.ndarray) -> jnp.ndarray:
 # --------------------------------------------------------------------------
 
 def init_moe(key, cfg: ModelConfig, dtype) -> Params:
+    """The router over all E experts; the weights of the held ones only."""
     mo = cfg.moe
     d, E, f = cfg.d_model, mo.num_experts, mo.d_ff_expert
+    held = mo.num_held
     ks = jax.random.split(key, 5)
     p = {
         "router": _dense_init(ks[0], (d, E), d, jnp.float32),   # router in fp32
-        "w_gate": _dense_init(ks[1], (E, d, f), d, dtype),
-        "w_up": _dense_init(ks[2], (E, d, f), d, dtype),
-        "w_down": _dense_init(ks[3], (E, f, d), f, dtype),
+        "w_gate": _dense_init(ks[1], (held, d, f), d, dtype),
+        "w_up": _dense_init(ks[2], (held, d, f), d, dtype),
+        "w_down": _dense_init(ks[3], (held, f, d), f, dtype),
     }
     if mo.router == "sigmoid":
         p["router_bias"] = _zeros((E,), jnp.float32)            # ds-v3 aux-free bias
@@ -499,9 +516,11 @@ def init_moe(key, cfg: ModelConfig, dtype) -> Params:
     return p
 
 
-def _router_probs(p: Params, x2d: jnp.ndarray, mo: MoEConfig):
+def _router_probs(p: Params, x2d: jnp.ndarray, mo: MoEConfig,
+                  precision=None):
     """x2d: (T, d) -> (gates (T,k), idx (T,k), probs_full (T,E) fp32)."""
-    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32), p["router"])
+    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32), p["router"],
+                        precision=precision)
     if mo.router == "sigmoid":
         probs = jax.nn.sigmoid(logits)
         sel = probs + p["router_bias"][None, :]                 # bias affects selection only
@@ -597,14 +616,63 @@ def apply_moe_gspmd(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> Tuple[jnp.nd
     return out.reshape(B, S, d), moe_aux_loss(probs, idx, mo)
 
 
+def apply_moe_dropless(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The held experts' part of the layer, dropping no row.
+
+    Every token is routed over all E experts; the (token, slot) pairs
+    whose expert this device holds are sorted by expert and run through
+    the held experts' SwiGLUs as grouped matrix products
+    (kernels/gmm), weighted by their gates and added back to their tokens,
+    plus the shared experts once. What the experts held elsewhere would add
+    is left out. Returns (out, [aux loss, rows the held experts computed,
+    rows of the busiest held expert])."""
+    from repro.kernels.gmm.ops import grouped_matmul
+
+    mo = cfg.moe
+    B, S, d = x.shape
+    T, k, held, f = B * S, mo.top_k, mo.num_held, mo.d_ff_expert
+    x2d = x.reshape(T, d)
+    # fp32 logits: on the TPU a default-precision f32 product is one bf16
+    # pass, which moves the top-k selection of near-tied experts
+    gates, idx, probs = _router_probs(p, x2d, mo,
+                                      precision=lax.Precision.HIGHEST)
+    local = idx.reshape(-1) - mo.first_held                      # (T*k,)
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held)          # group `held`: other chips
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    # a token routes to at most min(k, held) held experts: that many rows
+    # per token hold every held pair, whatever the routing
+    order = jnp.argsort(group, stable=True)[:T * min(k, held)]
+    tok = order // k
+    w_in = jnp.concatenate([p["w_gate"], p["w_up"]], axis=-1).astype(x.dtype)
+    gu = grouped_matmul(x2d[tok], w_in, sizes)                   # (rows, 2f)
+    h = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+    y = grouped_matmul(h, p["w_down"].astype(x.dtype), sizes)    # (rows, d)
+    w = jnp.where(mine[order], gates.reshape(-1)[order], 0.0)
+    out = jnp.zeros((T, d), jnp.float32).at[tok].add(
+        y.astype(jnp.float32) * w[:, None]).astype(x.dtype)
+    if mo.num_shared_experts:
+        out = out + apply_mlp(p["shared"], x2d)
+    stats = jnp.stack([moe_aux_loss(probs, idx, mo),
+                       jnp.sum(sizes).astype(jnp.float32),
+                       jnp.max(sizes).astype(jnp.float32)])
+    return out.reshape(B, S, d), stats
+
+
 def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """-> (out, [aux loss, rows the held experts computed, rows of the
+    busiest one]); the capacity paths count no rows (0, 0)."""
     impl = cfg.moe.impl
+    if impl == "dropless":
+        return apply_moe_dropless(p, x, cfg)
     if impl == "dense":
-        return apply_moe_dense(p, x, cfg)
-    if impl == "ep":
+        out, aux = apply_moe_dense(p, x, cfg)
+    elif impl == "ep":
         from repro.models.moe_ep import apply_moe_ep
-        return apply_moe_ep(p, x, cfg)
-    return apply_moe_gspmd(p, x, cfg)
+        out, aux = apply_moe_ep(p, x, cfg)
+    else:
+        out, aux = apply_moe_gspmd(p, x, cfg)
+    return out, jnp.stack([aux, jnp.zeros(()), jnp.zeros(())])
 
 
 # --------------------------------------------------------------------------

@@ -31,6 +31,7 @@ REPLICATE_BELOW = 4096          # leaves smaller than this stay replicated
 _RULES_3D = {
     # attention / rwkv projections (d, H, hd) — prefer heads, fall to head_dim
     "wq": ([-2, -1], [-3]), "wk": ([-2, -1], [-3]), "wv": ([-2, -1], [-3]),
+    "w_q": ([-2, -1], [-3]),
     "w_r": ([-2, -1], [-3]), "w_k": ([-2, -1], [-3]), "w_v": ([-2, -1], [-3]),
     # output projections (H, hd, d)
     "wo": ([-3, -2], [-1]), "w_o": ([-3, -2], [-1]),

@@ -83,7 +83,7 @@ def test_prefill_decode_matches_forward(arch):
 
 
 def test_all_archs_and_shapes_registered():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
     assert len(INPUT_SHAPES) == 4
     fams = {cfg.family for cfg in ARCHS.values()}
     assert fams == {"dense", "moe", "ssm", "hybrid", "audio", "vlm"}

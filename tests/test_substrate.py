@@ -238,6 +238,28 @@ def test_sdpa_qchunked_matches_reference():
                                rtol=2e-5)
 
 
+def test_sdpa_chunks_from_the_threshold_with_the_same_values_and_grads():
+    """From Sq = QCHUNK_THRESHOLD up, every config's full-sequence attention
+    is q-chunked with each chunk recomputed in the backward pass: the same
+    values and gradients as the unchunked reference."""
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    B, S, H, hd = 1, L.QCHUNK_THRESHOLD, 2, 16
+    q, k, v, g = (jax.random.normal(kk, (B, S, H, hd)) for kk in ks)
+    pos = jnp.broadcast_to(jnp.arange(S), (B, S))
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(g * f(
+            q, k, v, q_pos=pos, k_pos=pos, is_local=False, window=0,
+            softcap=0.0))
+
+    assert "scan" in str(jax.make_jaxpr(loss(L.sdpa))(q, k, v))
+    a = jax.value_and_grad(loss(L.sdpa), argnums=(0, 1, 2))(q, k, v)
+    b = jax.value_and_grad(loss(L.sdpa_reference), argnums=(0, 1, 2))(q, k, v)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-4,
+                                   rtol=2e-5)
+
+
 def test_maybe_scan_unrolled_equivalence():
     xs = jnp.arange(12.0).reshape(4, 3)
 

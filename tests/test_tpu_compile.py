@@ -10,8 +10,10 @@ load the TPU library and every pytest worker imports this file.
 
 Widths: the Gram stacks the chip smoke reaches at the paper's MNIST layout
 (group stacks d=5 × (c·m̃=200), the central and onboarding stacks at 250,
-battery_small's 16), flash attention at llama3.2-1b's heads and wkv6 at
-rwkv6-3b's.
+battery_small's 16), flash attention at llama3.2-1b's heads, wkv6 at
+rwkv6-3b's, and the grouped matmul, forward and backward under the silo
+vmap, at moonlight-16b-a3b's expert widths with the rows of 4 × 4096
+tokens × top-6.
 """
 import os
 from functools import partial
@@ -23,6 +25,7 @@ from jax.experimental.compilation_cache import compilation_cache
 
 from repro.configs import ARCHS
 from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.gmm.ops import grouped_matmul
 from repro.kernels.gram.ops import gram_batched
 from repro.kernels.rwkv6.ops import wkv6
 
@@ -85,3 +88,23 @@ def test_wkv6_compiles_for_v5e(one_chip):
     u = jax.ShapeDtypeStruct((heads, k), jnp.float32, sharding=one_chip)
     assert "tpu_custom_call" in _compiled_text(
         partial(wkv6, chunk=cfg.ssm.chunk, backend="pallas"), x, x, x, x, u)
+
+
+def test_grouped_matmul_and_its_vjp_compile_for_v5e(one_chip):
+    moe = ARCHS["moonlight-16b-a3b"].moe
+    d, f, held = ARCHS["moonlight-16b-a3b"].d_model, moe.d_ff_expert, 8
+    rows = 4 * 4096 * moe.top_k
+    mm = partial(grouped_matmul, backend="pallas")
+
+    def loss(x, w_in, w_out, sizes):
+        gu = mm(x, w_in, sizes)
+        h = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+        return jnp.sum(mm(h, w_out, sizes).astype(jnp.float32))
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((1,) + s, dtype, sharding=one_chip)
+
+    text = _compiled_text(jax.vmap(jax.grad(loss, argnums=(0, 1, 2))),
+                          shape(rows, d), shape(held, d, 2 * f),
+                          shape(held, f, d), shape(held, dtype=jnp.int32))
+    assert "tpu_custom_call" in text and "grouped_matmul_t_pallas" in text
