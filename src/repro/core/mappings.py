@@ -14,13 +14,23 @@ never shared under the protocol (privacy Layer 1). Kinds:
 
 Nonlinear maps are supported by composing `apply` with any row-wise
 nonlinearity upstream; the paper's experiments (and ours) use linear maps.
+
+`fit_maps` runs step 2 for a list of users at once, concurrently, as their
+institutions would each on its own machine.
 """
 from __future__ import annotations
 
+import functools
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor, wait
 from dataclasses import dataclass
-from typing import Optional
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
+from threadpoolctl import ThreadpoolController
+
+from repro import obs
 
 
 @dataclass
@@ -63,3 +73,51 @@ def fit_mapping(kind: str, X: np.ndarray, m_tilde: int,
         Q = _random_orthogonal(rng, m_tilde)
         return LinearMap(mu=mu, W=V @ Q)
     raise ValueError(f"unknown mapping kind {kind!r}")
+
+
+def fit_maps(kind: str, Xs: Sequence[np.ndarray], anchor: np.ndarray,
+             m_tilde: int, seeds: Sequence[int],
+             W: Optional[np.ndarray] = None
+             ) -> List[Tuple[LinearMap, np.ndarray, np.ndarray]]:
+    """Step 2 for a list of users: each user's private map f (fitted from
+    its own data and seed), X̃ = f(X) and Ã = f(anchor), in the order of
+    `Xs`.
+
+    Each result depends only on its own user, so the users are fitted
+    concurrently: min(users, cores) of them at a time in a thread pool
+    kept for the process, with OpenBLAS held to cores // workers threads
+    meanwhile (its full thread pool costs more than the arithmetic of one
+    user's small SVD and products, and several callers contend for it).
+    One user, or one core, is fitted inline at the default BLAS threads.
+    """
+    Xs = [np.asarray(X, np.float64) for X in Xs]
+
+    def fit(X, seed):
+        f = fit_mapping(kind, X, m_tilde, seed=seed, W=W)
+        return f, f(X), f(anchor)
+
+    cores = len(os.sched_getaffinity(0))
+    workers = min(len(Xs), cores)
+    if workers <= 1:
+        return [fit(X, s) for X, s in zip(Xs, seeds)]
+    pool, blas = _step2_pool()
+    # one fan-out at a time, so each restores the BLAS limit it found
+    with _step2_lock, blas.limit(limits=max(1, cores // workers),
+                                 user_api="blas"):
+        futures = [pool.submit(fit, X, s) for X, s in zip(Xs, seeds)]
+        wait(futures)
+    out = [fu.result() for fu in futures]
+    obs.count("feddcl.protocol.step2_concurrent_maps", len(out))
+    return out
+
+
+_step2_lock = threading.Lock()
+
+
+@functools.cache
+def _step2_pool() -> Tuple[ThreadPoolExecutor, ThreadpoolController]:
+    """The thread pool and BLAS controller `fit_maps` fans out with, made
+    on first use and kept for the process."""
+    return (ThreadPoolExecutor(len(os.sched_getaffinity(0)),
+                               thread_name_prefix="feddcl-step2"),
+            ThreadpoolController())

@@ -26,7 +26,7 @@ import numpy as np
 from repro import obs
 from repro.core import collab
 from repro.core.anchor import make_anchor
-from repro.core.mappings import LinearMap, fit_mapping
+from repro.core.mappings import LinearMap, fit_mapping, fit_maps
 
 
 @dataclass
@@ -182,17 +182,13 @@ class FedDCLSetup:
         st = self._require_onboard()
         be = collab.get_backend(st.backend)
         i = len(self.mappings)
-        row_f, row_x, row_a = [], [], []
-        for j, X in enumerate(Xs_new):
-            X = np.asarray(X, np.float64)
-            f = fit_mapping(st.mapping_kind, X, st.m_tilde,
-                            seed=st.seed * 1009 + i * 101 + j)
-            row_f.append(f)
-            Xt, At = f(X), f(self.anchor)
-            row_x.append(Xt)
-            row_a.append(At)
+        fitted = fit_maps(st.mapping_kind, Xs_new, self.anchor, st.m_tilde,
+                          [st.seed * 1009 + i * 101 + j
+                           for j in range(len(Xs_new))])
+        for j, (_, Xt, At) in enumerate(fitted):
             self.comm.log(f"user({i},{j})", f"dc({i})", "X~,A~,Y",
                           Xt, At, Ys_new[j])
+        row_f, row_x, row_a = (list(col) for col in zip(*fitted))
         A = np.concatenate(row_a, axis=1)
         st.inter_A.append(row_a)
         st.inter_X.append(row_x)
@@ -308,18 +304,16 @@ def run_protocol(
         inter_X: List[List[np.ndarray]] = []
         inter_A: List[List[np.ndarray]] = []
         with obs.span("feddcl.protocol.step2"):
+            roster = [(i, j) for i in range(d) for j in range(len(Xs[i]))]
+            fitted = iter(fit_maps(
+                mapping_kind, [Xs[i][j] for i, j in roster], anchor, m_tilde,
+                [seed * 1009 + i * 101 + j for i, j in roster], W=fixed_W))
             for i in range(d):
-                row_f, row_x, row_a = [], [], []
-                for j in range(len(Xs[i])):
-                    f = fit_mapping(mapping_kind,
-                                    np.asarray(Xs[i][j], np.float64), m_tilde,
-                                    seed=seed * 1009 + i * 101 + j, W=fixed_W)
-                    row_f.append(f)
-                    Xt, At = f(np.asarray(Xs[i][j], np.float64)), f(anchor)
-                    row_x.append(Xt)
-                    row_a.append(At)
+                row = [next(fitted) for _ in Xs[i]]
+                for j, (_, Xt, At) in enumerate(row):
                     comm.log(f"user({i},{j})", f"dc({i})", "X~,A~,Y",
                              Xt, At, Ys[i][j])
+                row_f, row_x, row_a = (list(col) for col in zip(*row))
                 mappings.append(row_f)
                 inter_X.append(row_x)
                 inter_A.append(row_a)

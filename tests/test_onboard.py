@@ -11,6 +11,8 @@ not compound past the bar).
 Bars: 1e-8 for the host backend (both paths are f64 LAPACK — agreement is
 near-exact), 1e-5 for the device backend (fp32 Gram/eigh/QR arithmetic).
 """
+import os
+
 import numpy as np
 import pytest
 
@@ -84,6 +86,34 @@ def test_onboard_silo_matches_full_recompute(backend, tol):
     ref = protocol.run_protocol(list(Xs) + [Xn], list(Ys) + [Yn],
                                 anchor=setup.anchor, **kw)
     _assert_setups_match(setup, ref, tol)
+
+
+@pytest.mark.parametrize("backend,tol", BACKENDS)
+def test_onboard_silo_fitted_concurrently_equals_one_at_a_time(
+        monkeypatch, backend, tol):
+    """A 3-user silo's step 2 runs in the thread pool when the process
+    may use several cores; maps, G's, X̂ and the upload log equal those
+    of the one-core run, which fits the users one at a time."""
+    rng = np.random.default_rng(12)
+    m = 6
+    Xs, Ys = _mkdata(rng, [2, 2], m)
+    Xn = [rng.standard_normal((int(rng.integers(25, 40)), m))
+          for _ in range(3)]
+    Yn = [rng.standard_normal((x.shape[0], 1)) for x in Xn]
+    kw = dict(m_tilde=4, anchor_r=100, seed=2, svd_backend=backend)
+    setups = []
+    for cores in (1, 8):
+        monkeypatch.setattr(os, "sched_getaffinity",
+                            lambda pid, n=cores: set(range(n)))
+        setup = protocol.run_protocol(Xs, Ys, onboard=True, **kw)
+        assert setup.onboard_silo(Xn, Yn) == 2
+        setups.append(setup)
+    pooled, serial = setups[1], setups[0]
+    _assert_setups_match(pooled, serial, tol)
+    assert pooled.comm.events == serial.comm.events
+    for f, rf in zip(pooled.mappings[2], serial.mappings[2]):
+        np.testing.assert_allclose(f.mu, rf.mu, rtol=1e-12)
+        assert np.abs(f.W - rf.W).max() < 1e-12
 
 
 @pytest.mark.parametrize("backend,tol", BACKENDS)
