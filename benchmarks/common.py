@@ -23,8 +23,7 @@ def run_all_methods(dataset: str, *, d: int = 5, c: int = 4, n_ij: int = 100,
                     n_test: int = 1000, seed: int = 0, lr: float = 1e-3,
                     non_iid: bool = False, dirichlet_alpha: float = 0.5,
                     methods=None, track_rounds: bool = False,
-                    engine: str = "host", svd_backend: str = "host",
-                    cache: bool = False) -> Dict:
+                    engine: str = "host", svd_backend: str = "host") -> Dict:
     """Returns {"metrics": {method: test metric}, "curves": {...}, "task": str}.
     Paper setup: batch 32; Centralized/Local/DC train `epochs`; FedAvg/FedDCL
     run `rounds` rounds × `local_epochs` epochs (§4.1).
@@ -32,10 +31,10 @@ def run_all_methods(dataset: str, *, d: int = 5, c: int = 4, n_ij: int = 100,
     All five methods train through the ONE federated engine
     (core/federated.py): `engine` selects the per-batch-dispatch host loop
     or the fully compiled lax.scan program; `svd_backend` selects the step-3
-    collaboration backend for FedDCL (DESIGN.md §3). cache=True (scan engine
-    only) routes every method through the shared compiled-plan cache with
-    stable loss/optimizer identities, so grid drivers (experiments/sweep.py,
-    exp3_groups) reuse executables across configs instead of recompiling."""
+    collaboration backend for FedDCL (DESIGN.md §3). The scan engine's plans
+    carry stable loss/optimizer identities, so grid scripts (exp3_groups)
+    reuse executables across configs from the shared plan cache instead of
+    recompiling."""
     cfg = PAPER_MLPS[dataset]
     methods = methods or ["Centralized", "Local", "FedAvg", "DC", "FedDCL"]
     n_train = d * c * n_ij
@@ -50,9 +49,9 @@ def run_all_methods(dataset: str, *, d: int = 5, c: int = 4, n_ij: int = 100,
     key = jax.random.PRNGKey(seed)
     loss = lambda p, x, y: mlp.mlp_per_example_loss(p, x, y, task)
     opt = adamw(lr)
-    cache_kw = (dict(cache=True, loss_id=("mlp_per_example_loss", task),
+    cache_kw = (dict(loss_id=("mlp_per_example_loss", task),
                      opt_id=("adamw", lr))
-                if cache and engine == "scan" else {})
+                if engine == "scan" else {})
     Xte_j, Yte_j = jnp.asarray(Xte), jnp.asarray(Yte)
 
     def metric(p, X=Xte_j):

@@ -5,8 +5,8 @@ as FedAvg and reaches comparable final RMSE.
 
 `--engine` selects the federated trainer (core/federated.py): "host" is the
 per-batch-dispatch reference loop, "scan" compiles the whole FL phase into
-one program — same schedule, same results, far fewer dispatches
-(benchmarks/fed_bench.py measures the gap).
+one cached program — same layout, same schedule, same results, far fewer
+dispatches.
 """
 from __future__ import annotations
 

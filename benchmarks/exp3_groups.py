@@ -19,31 +19,22 @@ import numpy as np
 from benchmarks.common import run_all_methods
 
 
-def run(fast: bool = False, engine: str = "host", cache: bool = False):
-    """The d-grid rides the generic sweep loop (experiments/sweep.run_sweep)
-    instead of an ad-hoc for-loop; engine="scan", cache=True additionally
-    share compiled FL executables across the grid via the plan cache."""
-    from experiments.sweep import run_sweep
-
+def run(fast: bool = False, engine: str = "host"):
+    """engine="scan" shares compiled FL executables across the d-grid via
+    the plan cache."""
     ds_grid = [1, 2, 4] if fast else [1, 2, 4, 6, 8, 10]
-
-    def one_d(case):
-        d = case["d"]
+    out = {}
+    for d in ds_grid:
         methods = ["Centralized", "DC", "FedDCL"] if d == 1 else \
             ["Centralized", "FedAvg", "DC", "FedDCL"]
         res = run_all_methods(
             "mnist", d=max(d, 1), c=4, n_ij=100,
             rounds=4 if fast else 15, local_epochs=2 if fast else 4,
             epochs=8 if fast else 30, n_test=500 if fast else 1000,
-            methods=methods, engine=engine, cache=cache)
+            methods=methods, engine=engine)
         print(f"d={d}: " + "  ".join(f"{k}={v:.4f}"
                                      for k, v in res["metrics"].items()))
-        return res["metrics"]
-
-    rows = run_sweep([{"d": d} for d in ds_grid], one_d, label="exp3",
-                     verbose=False)
-    out = {r["d"]: {k: v for k, v in r.items() if k not in ("d", "time_s")}
-           for r in rows}
+        out[d] = res["metrics"]
     os.makedirs("results", exist_ok=True)
     with open("results/exp3_groups.json", "w") as f:
         json.dump(out, f, indent=1)
@@ -110,5 +101,4 @@ if __name__ == "__main__":
         scenarios(fast="--fast" in sys.argv)
     else:
         run(fast="--fast" in sys.argv,
-            engine="scan" if "--engine=scan" in sys.argv else "host",
-            cache="--cache" in sys.argv)
+            engine="scan" if "--engine=scan" in sys.argv else "host")

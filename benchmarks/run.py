@@ -1,6 +1,6 @@
 """Benchmark entry point — one function per paper table/figure.
 Prints ``name,us_per_call,derived`` CSV (fast variants by default; pass
---full for the paper-scale runs recorded in EXPERIMENTS.md)."""
+--full for the paper-scale runs)."""
 from __future__ import annotations
 
 import argparse
@@ -20,15 +20,10 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="paper-scale runs (minutes on CPU)")
     ap.add_argument("--only", default=None,
-                    choices=["exp1", "exp2", "exp3", "comm", "kernels", "noniid"])
+                    choices=["exp1", "exp2", "exp3", "comm", "noniid"])
     args = ap.parse_args()
     fast = not args.full
     rows = []
-
-    if args.only in (None, "kernels"):
-        from benchmarks import kernels_bench
-        for name, us, derived in kernels_bench.run(fast=fast):
-            rows.append((name, us, derived))
 
     if args.only in (None, "exp1"):
         from benchmarks import exp1_convergence

@@ -75,7 +75,7 @@ class Report:
 
 
 def rel_diff_tree(a, b) -> float:
-    """max over leaves of max|a-b| / max|a| — fed_bench's rel_param_diff."""
+    """max over leaves of max|a-b| / max|a|: the relative parameter gap."""
     import jax
     import numpy as np
     return max(
@@ -103,7 +103,6 @@ def phase_fit(rep: Report, cfg, Xs, Ys):
 
     from repro.api import FedDCL
     from repro.core import federated, protocol
-    from repro.core.federated import bucket_pow2, pad_silo_data
     from repro.kernels.gram import ops as gram_ops
     from repro.models import mlp
     from repro.optim import adamw
@@ -138,23 +137,16 @@ def phase_fit(rep: Report, cfg, Xs, Ys):
     rep.check("fit", "step3 device vs host rel_frobenius", f"{rel:.3e}",
               "1e-3", rel <= 1e-3)
 
-    # step 4: the cached scan plan vs the host engine on the SAME bucketed
-    # layout (tests/test_plan_cache.py), on this chip
+    # step 4: the cached scan plan vs the host engine, both on the one
+    # bucketed layout (tests/test_plan_cache.py), on this chip
     t0 = time.perf_counter()
-    silos = setup.fed_silos()
-    bs = model.batch_size
-    n_max = max(x.shape[0] for x, _ in silos)
-    padded = pad_silo_data(silos, bs,
-                           min_batches=bucket_pow2(-(-n_max // bs)),
-                           min_silos=bucket_pow2(len(silos)))
     loss = lambda p, x, y: mlp.mlp_per_example_loss(p, x, y, cfg.task)
     init = mlp.init_mlp_params(jax.random.PRNGKey(SEED), cfg.reduced_dim,
                                cfg.hidden, cfg.out_dim)
-    host_fl = federated._run_host(
-        federated._make_batch_loss(loss, True, 0.0), init, padded,
-        opt=adamw(model.lr), rounds=ROUNDS, local_epochs=LOCAL_EPOCHS,
-        aggregator="fedavg", seed=SEED, eval_fn=None, per_example=True,
-        reset_opt=True)
+    host_fl = federated.run_federated(
+        loss, init, setup.fed_silos(), opt=adamw(model.lr), rounds=ROUNDS,
+        local_epochs=LOCAL_EPOCHS, batch_size=model.batch_size,
+        engine="host", seed=SEED)
     print(f"  host FL engine reference: {time.perf_counter() - t0:.3f} s",
           flush=True)
     rel = rel_diff_tree(host_fl.params, result.params)
@@ -313,7 +305,7 @@ def phase_four_chips(rep: Report):
     loss = lambda p, x, y: mlp.mlp_per_example_loss(p, x, y, cfg.task)
     leaves = len(jax.tree.leaves(params))
     bs = 32
-    padded = pad_silo_data(silos, bs, min_silos=8)
+    padded = pad_silo_data(silos, bs, shards=4)
     args = federated._plan_args(padded, SEED, ROUNDS)
     for agg in ("fedavg", "median"):
         rounds = 1 if agg == "fedavg" else ROUNDS
@@ -351,7 +343,7 @@ def phase_four_chips(rep: Report):
             batch_size=padded.batch_size, opt=sgd(1e-2),
             batch_loss=federated._make_batch_loss(loss, True, 0.0),
             rounds=ROUNDS, local_epochs=LOCAL_EPOCHS, aggregator=agg,
-            masked=True, mesh=mesh)
+            mesh=mesh)
         # the program the plan emits (tests/test_fed_sharded.py's counts):
         # fedavg psums every param leaf and the loss; median gathers every
         # leaf and the availability mask, and psums the loss

@@ -1,3 +1,2 @@
-"""Reproducible experiment drivers (committed, unlike the untracked
-prototypes they replace): config-grid sweeps through the public
-FedDCL.fit() API and the compiled-plan cache."""
+"""Reproducible experiment scripts: the robust-aggregation ablation
+(`robust_ablation.py`) through `run_federated`."""

@@ -62,7 +62,8 @@ def main(argv=None) -> int:
 
     enable_persistent_compilation_cache()
     # sized so every padded tensor (and the closure-captured control slice)
-    # clears --min-elems: 3 silos x 7 batches x 8 x 16 features
+    # clears --min-elems: 3 silos (bucketed to 4) x 7 batches (to 8) x 8 x 16
+    # features
     rng = np.random.default_rng(0)
     feat = 16
     w_true = rng.standard_normal((feat, 1))
@@ -81,13 +82,12 @@ def main(argv=None) -> int:
 
     def check(name, *, mesh, aggregator, collect):
         padded = pad_silo_data(silos, 8,
-                               min_silos=-(-len(silos) // shards) * shards
-                               if mesh is not None else 0)
+                               shards=shards if mesh is not None else 1)
         plan = make_fl_plan(
             num_silos=padded.num_silos, num_batches=padded.num_batches,
             batch_size=padded.batch_size, opt=adamw(1e-2),
             batch_loss=batch_loss, rounds=2, local_epochs=2,
-            aggregator=aggregator, masked=True, collect=collect, mesh=mesh)
+            aggregator=aggregator, collect=collect, mesh=mesh)
         lowered = lower_fl_plan(plan, params, padded, rounds=2)
         assert_no_baked_data(lowered, min_elems=args.min_elems)
         census = collective_census(lowered)

@@ -9,7 +9,7 @@ code inside individual tests:
     {all-reduce: leaves+1} per hierarchy level, a robust plan
     {all-reduce: 1, all-gather: leaves+1}, and an UNSHARDED plan no
     collective at all (tests/test_fed_sharded.py, tests/test_fed_robust.py,
-    benchmarks/fed_bench.py --sharded all consume this one function now).
+    scripts/feddcl_audit.py and chip_smoke.py all consume this one function).
 
 `assert_no_baked_data(lowered)` — the artifact-level privacy check. Before
     data-as-arguments plans (PR 3) the jitted runner closed over tenant

@@ -6,7 +6,7 @@ amortize everything else; this facade makes the COMPUTE side match. The
 first ``fit()`` of a given shape bucket pays the scan-engine trace+compile
 (~1 s on CPU); every later ``fit()`` whose padded shapes land in the same
 bucket reuses the executable and costs milliseconds (the plan cache,
-core/federated.py, DESIGN.md §6). Across processes, the persistent XLA
+core/plans.py, DESIGN.md §6). Across processes, the persistent XLA
 compilation cache (`enable_persistent_compilation_cache`) turns even the
 first call of a fresh process into a disk hit.
 
@@ -31,8 +31,7 @@ import numpy as np
 from jax.experimental.compilation_cache import compilation_cache
 
 from repro.core import protocol
-from repro.core.federated import (FLResult, PlanCache, default_plan_cache,
-                                  run_federated)
+from repro.core.federated import FLResult, run_federated
 from repro.core.protocol import FedDCLSetup
 from repro.models import mlp
 from repro.optim import adamw
@@ -119,6 +118,7 @@ class FedDCL:
         self.engine = engine
         self.seed = seed
         self.reset_opt_per_round = reset_opt_per_round
+        # True: the process-wide plan cache; or a PlanCache of the caller's
         self.cache = cache
         self.eval_fn = eval_fn
         # hostile-world federation knobs (DESIGN.md §8): aggregator may be

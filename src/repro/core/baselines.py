@@ -28,9 +28,9 @@ def sgd_train(loss_fn, params, X, Y, *, opt: Optimizer, epochs: int,
     """Plain minibatch training used by Centralized / Local / DC — the d=1
     degenerate case of the federated engine: one silo, each "round" is one
     epoch, optimizer state carried across rounds, FedAvg over one silo is
-    the identity. engine="scan" compiles the whole run into one dispatch;
-    cache/loss_id/opt_id route it through the shared compiled-plan cache
-    (core/federated.py) exactly like the federated methods."""
+    the identity. engine="scan" compiles the whole run into one cached
+    dispatch exactly like the federated methods; cache picks the PlanCache
+    (core/plans.py) and loss_id/opt_id give the plan a stable identity."""
     res = run_federated(
         loss_fn, params, [(np.asarray(X), np.asarray(Y))], opt=opt,
         rounds=epochs, local_epochs=1, batch_size=batch_size, seed=seed,

@@ -2,41 +2,38 @@
 
 ONE trainer serves every method (`run_federated`; Centralized / Local / DC
 reach it through `baselines.sgd_train`, the d=1 degenerate case), with two
-interchangeable engines mirroring the step-3 `CollabBackend` split
-(DESIGN.md §3, §4):
+routes mirroring the step-3 `CollabBackend` split (DESIGN.md §3, §4):
 
   engine="host" — the paper-faithful reference: a NumPy-orchestrated Python
       loop that dispatches one tiny jitted SGD step per minibatch per epoch
       per silo per round (thousands of device launches for a 20-round run).
-  engine="scan" — the compiled form: the WHOLE FL phase is one jitted
-      program. Silo datasets are zero-padded to a (d, n_slots, m) stack with
-      per-sample masks, minibatch order comes from `jax.random.permutation`
-      folded from the seed, local epochs and minibatches are inner
-      `lax.scan`s with the per-silo step vmapped over the leading silo dim,
-      and rounds are an outer `lax.scan` whose boundary is the weighted
-      `fedavg_sync`. A 20-round × 4-epoch run is ONE dispatch.
+  engine="scan" — the production route: the WHOLE FL phase is one cached,
+      compiled PLAN (`make_fl_plan`). Minibatch order comes from
+      `jax.random.permutation` folded from the seed, local epochs and
+      minibatches are inner `lax.scan`s with the per-silo step vmapped over
+      the leading silo dim, and rounds are an outer `lax.scan` whose
+      boundary is the weighted `fedavg_sync`. A 20-round × 4-epoch run is
+      ONE dispatch.
 
-Both engines consume the same padded layout (`pad_silo_data`) and the same
+Both routes train on ONE padded layout (`pad_silo_data`: silos and batches
+rounded up to `bucket_pow2` buckets, sample masks on the padding) and ONE
 batch schedule (`round_perms`), so with the same seed they agree to float
 tolerance on parameters and loss trajectories (tests/test_fed_engine.py).
 FedAvg / FedProx / FedSGD all route through the same code path.
 
-The scan engine's compiled unit is a PLAN (`make_fl_plan`): a jitted
-program taking ALL tenant data (padded stacks, weights, PRNG key) as
-arguments, so executables are reusable across tenants. `PlanCache` stores
-plans keyed on the full compile signature with silo/batch axes rounded up
-to shape buckets (`run_federated(cache=True)`, DESIGN.md §6) — the
-amortization layer that makes sweeps and many-tenant traffic pay the
-~1 s trace+compile once instead of per call.
+A plan takes ALL tenant data (padded stacks, weights, PRNG key) as
+arguments, so executables are reusable across tenants: the scan route
+looks every plan up in a `PlanCache` (core/plans.py, DESIGN.md §6) keyed on
+the bucketed layout and the static config — the process-wide one unless
+the caller passes its own — and pays the trace+compile once per bucket.
 
-Plans also run MULTI-DEVICE (`make_fl_plan(mesh=...)`, DESIGN.md §7): the
+Plans also run MULTI-DEVICE (`run_federated(mesh=...)`, DESIGN.md §7): the
 rounds-scan is wrapped in one `shard_map` with the padded silo stack split
 over the mesh's silo axes (("pod", "data") jointly on multi-pod meshes)
 and params replicated; the local phase is collective-free per shard and
 the round boundary lowers to one weighted all-reduce per leaf per
 hierarchy level. With `eval_fn`, plans are `StreamedPlan` chunk steps
-that bound eval memory to eval_chunk × |params| regardless of rounds
-(no more (rounds, |params|) stack inside the scan).
+that bound eval memory to eval_chunk × |params| regardless of rounds.
 
 Loss reporting: `history[rnd]["loss"]` is the sample-weighted mean over
 silos of each silo's final-local-epoch masked mean loss (the scan engine
@@ -46,12 +43,11 @@ HOSTILE-WORLD federation (DESIGN.md §8): the aggregation boundary can be
 made adversarial-robust (`aggregator="median" | "trimmed_mean" | "krum"` —
 masked coordinate statistics over the per-silo deltas, computed from a
 cross-silo all_gather instead of the weighted psum when sharded), silos can
-drop out mid-training (`dropout_rate` / an explicit `availability` matrix —
-the schedule is drawn on HOST, outside any shard_map manual region, and
-folded into per-round normalized weights so unavailable silos are exact
-no-ops under the §4 mask rules), and per-silo deltas can be scaled
-(`silo_scale` — the gradient-scaling attacker injection point,
-core/privacy.py).
+drop out mid-training (`dropout_rate` — the schedule is drawn on HOST,
+outside any shard_map manual region, and folded into per-round normalized
+weights so unavailable silos are exact no-ops under the §4 mask rules), and
+per-silo deltas can be scaled (`silo_scale` — the gradient-scaling
+attacker injection point, core/privacy.py).
 
 The mesh-collective primitives (`silo_vmap_step`, `fedavg_sync`,
 `scan_local_steps`) are the production form on the TPU mesh: parameters
@@ -72,6 +68,11 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
+# PlanCache / default_plan_cache / clear_plan_cache are re-exported: callers
+# of step 4 reach the plan cache through this module
+from repro.core.plans import (PlanCache, bucket_pow2,  # noqa: F401
+                              clear_plan_cache, default_plan_cache,
+                              tree_signature)
 from repro.optim import Optimizer, apply_updates
 from repro.shardingx.policy import batch_spec
 
@@ -98,10 +99,9 @@ class PaddedSilos:
     n_slots = num_batches * batch_size ≥ max_i n_i, so every minibatch has a
     static shape and an epoch is exactly one permutation of the slot axis.
 
-    The silo axis may carry trailing EMPTY silos (sizes 0, all-padding) and
-    the slot axis trailing all-padding batches — how the plan cache buckets
-    ragged tenant shapes onto shared executables (pad_silo_data's
-    min_silos / min_batches).
+    The silo axis carries trailing EMPTY silos (sizes 0, all-padding) and
+    the slot axis trailing all-padding batches up to the `pad_silo_data`
+    buckets — how ragged tenant shapes share compiled executables.
     """
     X: np.ndarray
     Y: np.ndarray
@@ -123,28 +123,32 @@ class PaddedSilos:
 def pad_silo_data(silo_data: Sequence[Tuple[np.ndarray, np.ndarray]],
                   batch_size: Optional[int] = None,
                   fill: float = 0.0,
-                  min_batches: int = 0,
-                  min_silos: int = 0) -> PaddedSilos:
-    """Stack ragged per-silo (X_i, Y_i) into the padded engine layout.
+                  shards: int = 1) -> PaddedSilos:
+    """Stack ragged per-silo (X_i, Y_i) into step 4's ONE padded layout,
+    the layout both engines train on and the plan cache keys on:
 
-    batch_size=None means full-batch (FedSGD): one batch of n_max slots.
-    `fill` sets the value written into padded X rows — 0.0 in production;
-    the padding-leak property test passes garbage to prove masks win.
-    min_batches / min_silos round the layout UP to a shape bucket (extra
-    all-padding batches / extra zero-size silos) so different tenants share
-    one compiled executable (the plan cache, DESIGN.md §6). Empty silos get
-    sample weight zero everywhere, so they are exact no-ops.
+      - silos: `bucket_pow2(d)`, then up to a multiple of `shards` (the
+        silo-shard count of a mesh);
+      - batches per silo: `bucket_pow2(ceil(n_max / batch_size))`;
+      - batch_size=None (FedSGD): one full batch of `bucket_pow2(n_max)`
+        slots.
+
+    Rounding UP to these buckets lets different tenants share one compiled
+    executable (DESIGN.md §6). Extra silos are empty and extra batches
+    all-padding; both carry sample weight zero everywhere, so they are
+    exact no-ops. `fill` sets the value written into padded X rows — 0.0
+    in production; the padding-leak property test passes garbage to prove
+    masks win.
     """
     sizes = np.array([np.asarray(x).shape[0] for x, _ in silo_data], np.int64)
     n_max = int(sizes.max())
     if batch_size is None:
-        bs, nb = max(n_max, 1), 1
+        bs, nb = bucket_pow2(n_max), 1
     else:
         bs = int(batch_size)
-        nb = -(-n_max // bs)
-    nb = max(nb, int(min_batches), 1)
+        nb = bucket_pow2(-(-n_max // bs))
     n_slots = bs * nb
-    d = max(len(silo_data), int(min_silos))
+    d = -(-bucket_pow2(len(silo_data)) // shards) * shards
     if d > len(silo_data):
         sizes = np.concatenate([sizes, np.zeros(d - len(silo_data), np.int64)])
     x0, y0 = np.asarray(silo_data[0][0]), np.asarray(silo_data[0][1])
@@ -397,23 +401,23 @@ def _make_batch_loss(loss_fn, per_example: bool, fedprox_mu: float):
     return batch_loss
 
 
-def _make_sgd_step(batch_loss, opt: Optimizer, masked: bool = False):
-    """masked=True additionally suppresses the optimizer update for batches
-    with ZERO real samples: without the guard an all-padding batch would
-    still advance the step counter, decay momentum, and coast parameters on
-    stale Adam state — so small ragged silos would take extra effective
-    steps. With it, all-padding batches are exact no-ops and a silo's
-    training is the sequence of its real-sample batches only."""
+def _make_sgd_step(batch_loss, opt: Optimizer):
+    """One masked optimizer step. The update is suppressed for batches with
+    ZERO real samples: without the guard an all-padding batch would still
+    advance the step counter, decay momentum, and coast parameters on stale
+    Adam state — so small ragged silos would take extra effective steps.
+    With it, all-padding batches (and the batches of empty or dropped
+    silos) are exact no-ops and a silo's training is the sequence of its
+    real-sample batches only."""
     def step(p, opt_state, x, y, w, ref):
         loss, grads = jax.value_and_grad(batch_loss)(p, x, y, w, ref)
         updates, new_state = opt.update(grads, opt_state, p)
         new_p = apply_updates(p, updates)
-        if masked:
-            has_real = jnp.sum(w) > 0
-            new_p = jax.tree.map(
-                lambda a, b: jnp.where(has_real, a, b), new_p, p)
-            new_state = jax.tree.map(
-                lambda a, b: jnp.where(has_real, a, b), new_state, opt_state)
+        has_real = jnp.sum(w) > 0
+        new_p = jax.tree.map(
+            lambda a, b: jnp.where(has_real, a, b), new_p, p)
+        new_state = jax.tree.map(
+            lambda a, b: jnp.where(has_real, a, b), new_state, opt_state)
         return new_p, new_state, loss
 
     return step
@@ -488,100 +492,6 @@ def _all_gather_tree(tree: Any, axes: Sequence[str]) -> Any:
 
 
 # ==========================================================================
-# 1b. The compiled-plan cache: shape-bucketed executable reuse
-# ==========================================================================
-
-def bucket_pow2(n: int) -> int:
-    """Round n up to the next power of two (the default bucket policy):
-    ≤ 2× padding waste, log-many buckets over any tenant population."""
-    return 1 << (max(int(n), 1) - 1).bit_length()
-
-
-def _tree_signature(tree: Any) -> Tuple:
-    """Hashable (structure, leaf shapes/dtypes) fingerprint of a pytree."""
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    return (str(treedef),
-            tuple((tuple(np.shape(l)), str(jnp.asarray(l).dtype))
-                  for l in leaves))
-
-
-class PlanCache:
-    """LRU cache of compiled FL plans keyed on the full compile signature.
-
-    A plan (make_fl_plan) takes all tenant data as arguments, so two
-    run_federated calls whose padded layouts land in the same shape bucket
-    — (num_silos, num_batches, batch_size, feature/target shapes, params
-    signature) — and share the same static config (aggregator, rounds,
-    epochs, reset_opt, collect, per_example, fedprox_mu, loss/opt identity)
-    reuse ONE jitted callable and therefore ONE XLA executable. Bucketing
-    (bucket_silos / bucket_batches, default next-pow2) rounds the silo and
-    batch axes UP so a new tenant's ragged shapes hit an existing
-    executable instead of compiling a fresh one.
-
-    Counters: hits / misses / evictions; a miss builds (and on first call
-    compiles) a new plan, so `misses` == number of executables built
-    through this cache.
-    """
-
-    def __init__(self, max_plans: int = 64,
-                 bucket_silos: Callable[[int], int] = bucket_pow2,
-                 bucket_batches: Callable[[int], int] = bucket_pow2):
-        from collections import OrderedDict
-        self._plans: "OrderedDict[Tuple, Tuple]" = OrderedDict()
-        self.max_plans = max_plans
-        self.bucket_silos = bucket_silos
-        self.bucket_batches = bucket_batches
-        self.hits = 0
-        self.misses = 0
-        self.evictions = 0
-
-    def __len__(self) -> int:
-        return len(self._plans)
-
-    def stats(self) -> Dict[str, int]:
-        return {"hits": self.hits, "misses": self.misses,
-                "evictions": self.evictions, "plans": len(self._plans)}
-
-    def clear(self) -> None:
-        self._plans.clear()
-        self.hits = self.misses = self.evictions = 0
-
-    def lookup(self, key: Tuple, build: Callable[[], Callable],
-               pins: Tuple = ()) -> Tuple[Callable, bool]:
-        """Return (plan, was_hit). `pins` holds strong references (loss_fn,
-        opt) for entries keyed on object identity, so a cached id() can
-        never be recycled by the allocator while the entry lives."""
-        if key in self._plans:
-            self._plans.move_to_end(key)
-            self.hits += 1
-            return self._plans[key][0], True
-        plan = build()
-        self._plans[key] = (plan, pins)
-        self.misses += 1
-        while len(self._plans) > self.max_plans:
-            self._plans.popitem(last=False)
-            self.evictions += 1
-        return plan, False
-
-
-_DEFAULT_PLAN_CACHE: Optional[PlanCache] = None
-
-
-def default_plan_cache() -> PlanCache:
-    """The process-wide plan cache used by ``run_federated(cache=True)``
-    and the FedDCL.fit() API."""
-    global _DEFAULT_PLAN_CACHE
-    if _DEFAULT_PLAN_CACHE is None:
-        _DEFAULT_PLAN_CACHE = PlanCache()
-    return _DEFAULT_PLAN_CACHE
-
-
-def clear_plan_cache() -> None:
-    if _DEFAULT_PLAN_CACHE is not None:
-        _DEFAULT_PLAN_CACHE.clear()
-
-
-# ==========================================================================
 # 2. The unified federated engine
 # ==========================================================================
 
@@ -589,7 +499,7 @@ def clear_plan_cache() -> None:
 class FLResult:
     params: Any
     history: List[Dict[str, float]]
-    cache_stats: Optional[Dict[str, int]] = None   # set when a PlanCache ran
+    cache_stats: Optional[Dict[str, int]] = None   # set by every scan run
 
 
 def fedavg_average(params_list: Sequence[Any], weights: Sequence[float]) -> Any:
@@ -622,10 +532,8 @@ def run_federated(
     loss_id: Optional[Tuple] = None,
     opt_id: Optional[Tuple] = None,
     mesh=None,
-    silo_axes: Optional[Sequence[str]] = None,
     eval_chunk: int = 8,
     dropout_rate: float = 0.0,
-    availability: Optional[np.ndarray] = None,
     silo_scale: Optional[Sequence[float]] = None,
     trim_frac: float = 0.2,
     krum_f: int = 1,
@@ -636,42 +544,40 @@ def run_federated(
 
     loss_fn takes (params, x, y) and returns either a (batch,) per-example
     loss vector (preferred: ragged silos are zero-padded and masked) or a
-    scalar batch mean (legacy; only valid when no padding is needed, i.e.
-    every silo has the same size divisible by batch_size). `per_example` is
-    auto-detected from the output shape when None.
+    scalar batch mean (legacy; only valid when the layout needs no padding,
+    i.e. a power-of-two silo count of equal-size silos whose batch count is
+    a power of two). `per_example` is auto-detected from the output shape
+    when None.
 
-    engine="host" is the paper-faithful per-batch-dispatch loop;
-    engine="scan" compiles the whole schedule into one lax.scan program.
-    Both use the same jax.random batch schedule and agree to float
-    tolerance for the same seed.
+    Both engines train on the one padded layout of `pad_silo_data` with the
+    one batch schedule of `round_perms`, and agree to float tolerance for
+    the same seed:
+
+      engine="host" — the per-batch-dispatch reference loop; `cache` must be
+          omitted.
+      engine="scan" — the whole schedule as one compiled plan, looked up in
+          a `PlanCache` (DESIGN.md §6): the process-wide one when `cache` is
+          omitted or True, else the `PlanCache` instance given. Every call
+          whose compile signature matches shares the executable, so a
+          sweep's 2nd–Nth configs cost milliseconds. loss_id / opt_id give
+          the loss/optimizer a stable cache identity (e.g.
+          ("mlp_per_example_loss", task) / ("adamw", lr)); when omitted,
+          object identity is used, which only hits when the caller reuses
+          the exact same callables. cache_stats on the result records
+          {hit, hits, misses, evictions, plans}.
 
     reset_opt_per_round=False carries silo optimizer state across rounds
     (used by sgd_train, where rounds are plain epochs).
 
-    cache=True (or a PlanCache instance) routes the scan engine through the
-    shape-bucketed compiled-plan cache (DESIGN.md §6): the padded layout is
-    rounded UP to the cache's silo/batch buckets and the compiled
-    executable is shared with every other call whose compile signature
-    matches — a sweep's 2nd–Nth configs then cost milliseconds. Because
-    bucketing changes n_slots (and so the minibatch schedule), the bucketed
-    layout is the canonical layout of a cached run: two cached runs agree
-    bitwise, and they agree with the host engine on the SAME bucketed
-    layout to engine tolerance. loss_id / opt_id give the loss/optimizer a
-    stable cache identity (e.g. ("mlp_per_example_loss", task) /
-    ("adamw", lr)); when omitted, object identity is used, which only hits
-    when the caller reuses the exact same callables. cache_stats on the
-    result records {hit, hits, misses, evictions, plans}.
-
     mesh (scan engine only) runs the FL phase sharded: the padded silo
-    stack is placed over the mesh's silo axes (silo_axes, default
-    `default_silo_axes` — ("pod", "data") jointly when both exist) via
-    shard_map, with hierarchical round-boundary psums as the ONLY
-    collectives (DESIGN.md §7). The silo count is padded up to a multiple
-    of the silo-shard count with empty no-op silos, so results match the
-    unsharded engine to float tolerance. eval_chunk bounds the eval path's
-    memory: with eval_fn, per-round params stream to host eval_chunk
-    rounds per dispatch instead of materializing a (rounds, |params|)
-    stack on device.
+    stack is placed over the mesh's silo axes (`default_silo_axes` —
+    ("pod", "data") jointly when both exist) via shard_map, with
+    hierarchical round-boundary psums as the ONLY collectives (DESIGN.md
+    §7). The silo count is padded up to a multiple of the silo-shard count
+    with empty no-op silos, so results match the unsharded engine to float
+    tolerance. eval_chunk bounds the eval path's memory: with eval_fn,
+    per-round params stream to host eval_chunk rounds per dispatch instead
+    of materializing a (rounds, |params|) stack on device.
 
     HOSTILE-WORLD options (DESIGN.md §8): aggregator may also be one of
     `ROBUST_AGGREGATORS` — "median" / "trimmed_mean" (trim_frac per tail) /
@@ -679,59 +585,30 @@ def run_federated(
     statistic over the available silos' submissions instead of the
     sample-weighted mean (sharded: via a cross-silo all_gather instead of
     the psum). dropout_rate draws a per-(round, silo) Bernoulli availability
-    schedule on host (`make_dropout_schedule`; `availability` passes an
-    explicit (rounds, num_real_silos) {0,1} matrix instead); unavailable
-    silos train nothing that round (exact no-op under the §4 mask rules)
-    and carry zero aggregation weight. silo_scale (num_real_silos,)
-    multiplies each silo's submitted round delta — the gradient-scaling
-    attacker's injection point (core/privacy.py); 1.0 is an exact no-op.
+    schedule on host (`make_dropout_schedule`); unavailable silos train
+    nothing that round (exact no-op under the §4 mask rules) and carry zero
+    aggregation weight. silo_scale (num_real_silos,) multiplies each silo's
+    submitted round delta — the gradient-scaling attacker's injection point
+    (core/privacy.py); 1.0 is an exact no-op.
     """
     if aggregator not in AGGREGATORS:
         raise ValueError(f"unknown aggregator {aggregator!r}; "
                          f"choose one of {AGGREGATORS}")
     if engine not in ("host", "scan"):
         raise ValueError(f"unknown engine {engine!r}; choose 'host' or 'scan'")
-    if mesh is not None and engine != "scan":
-        raise ValueError("mesh=... requires engine='scan' — the host engine "
-                         "is a per-batch dispatch loop and cannot shard the "
-                         "silo axis")
+    if engine == "host" and (cache is not None or mesh is not None):
+        raise ValueError("cache=... and mesh=... require engine='scan' — the "
+                         "host engine is a per-batch dispatch loop: it "
+                         "compiles no plan and cannot shard the silo axis")
+    if not (cache is None or cache is True or isinstance(cache, PlanCache)):
+        raise ValueError(f"cache must be omitted, True or a PlanCache; got "
+                         f"{cache!r} — every scan run compiles into a plan "
+                         "cache")
     with obs.span("feddcl.fl.prepare"):
-        plan_cache: Optional[PlanCache] = None
-        if cache is not None and cache is not False:
-            if engine != "scan":
-                raise ValueError("cache=... requires engine='scan' — the "
-                                 "plan cache stores compiled scan-engine "
-                                 "executables")
-            plan_cache = cache if isinstance(cache, PlanCache) \
-                else default_plan_cache()
-        axes: Optional[Tuple[str, ...]] = None
-        shards = 1
-        if mesh is not None:
-            axes = tuple(silo_axes) if silo_axes else default_silo_axes(mesh)
-            shards = num_silo_shards(mesh, axes)
-
-        def shard_multiple(d: int) -> int:
-            """Round a silo count up to the silo-shard count (extra silos are
-            empty → exact no-ops under the mask rules)."""
-            return -(-d // shards) * shards
-
-        if plan_cache is not None:
-            n_max = max(np.asarray(x).shape[0] for x, _ in silo_data)
-            if aggregator == "fedsgd":
-                bs_eff: Optional[int] = plan_cache.bucket_batches(n_max)
-                min_nb = 1
-            else:
-                bs_eff = batch_size
-                min_nb = plan_cache.bucket_batches(-(-n_max // batch_size))
-            padded = pad_silo_data(
-                silo_data, bs_eff, fill=pad_fill, min_batches=min_nb,
-                min_silos=shard_multiple(
-                    plan_cache.bucket_silos(len(silo_data))))
-        else:
-            padded = pad_silo_data(
-                silo_data, None if aggregator == "fedsgd" else batch_size,
-                fill=pad_fill,
-                min_silos=shard_multiple(len(silo_data)) if shards > 1 else 0)
+        shards = 1 if mesh is None else num_silo_shards(mesh)
+        padded = pad_silo_data(
+            silo_data, None if aggregator == "fedsgd" else batch_size,
+            fill=pad_fill, shards=shards)
         if per_example is None:
             per_example = _detect_per_example(loss_fn, init_params, padded)
         if not per_example and padded.has_padding:
@@ -741,36 +618,19 @@ def run_federated(
                 "(batch-mean) loss cannot mask — pass a per-example loss "
                 "(returning a (batch,) vector, e.g. "
                 "models.mlp.mlp_per_example_loss) or equal-size silos "
-                "divisible by batch_size")
-        if availability is not None and dropout_rate:
-            raise ValueError("pass either dropout_rate or an explicit "
-                             "availability matrix, not both")
+                "whose count and batch count are powers of two")
         av: Optional[np.ndarray] = None
-        if availability is not None:
-            av = np.asarray(availability, np.float32)
-            if av.shape[0] != rounds or av.shape[1] > padded.num_silos:
-                raise ValueError(
-                    f"availability must be (rounds, "
-                    f"num_silos≤{padded.num_silos}) for rounds={rounds}; "
-                    f"got {av.shape}")
-            if av.shape[1] < padded.num_silos:
-                # bucket-padding silos are empty → never available
-                av = np.concatenate(
-                    [av, np.zeros((rounds, padded.num_silos - av.shape[1]),
-                                  np.float32)], axis=1)
-        elif dropout_rate:
+        if dropout_rate:
             # draw over the REAL silo count so the schedule is invariant to
             # bucket/shard padding (a d=6 tenant gets the same draws whether
-            # the layout pads to 6, 8, or 16 silos), then zero-pad the
-            # columns
+            # the layout pads to 8 or 16 silos), then zero-pad the columns
             d_real = len(silo_data)
             av = make_dropout_schedule(seed, rounds, d_real,
                                        float(dropout_rate),
                                        sizes=padded.sizes[:d_real])
-            if padded.num_silos > d_real:
-                av = np.concatenate(
-                    [av, np.zeros((rounds, padded.num_silos - d_real),
-                                  np.float32)], axis=1)
+            av = np.concatenate(
+                [av, np.zeros((rounds, padded.num_silos - d_real),
+                              np.float32)], axis=1)
         scale_vec: Optional[np.ndarray] = None
         if silo_scale is not None:
             s = np.asarray(silo_scale, np.float32).reshape(-1)
@@ -779,11 +639,6 @@ def run_federated(
                                  f"{padded.num_silos} silos")
             scale_vec = np.ones(padded.num_silos, np.float32)
             scale_vec[:s.shape[0]] = s
-        # dropout makes whole rounds all-padding for the dropped silos, so
-        # the exact-no-op step guard must be on even when the layout itself
-        # is dense
-        needs_mask = padded.has_padding or (
-            av is not None and not np.all(av > 0))
         robust = aggregator in ROBUST_AGGREGATORS
         mu = fedprox_mu if aggregator == "fedprox" else 0.0
         batch_loss = _make_batch_loss(loss_fn, per_example, mu)
@@ -791,20 +646,20 @@ def run_federated(
                   int(padded.sizes.sum()) * local_epochs * rounds)
         obs.count("feddcl.fl.slot_steps",
                   padded.num_silos * padded.n_slots * local_epochs * rounds)
-        plan = was_hit = None
-        if plan_cache is not None:
+        if engine == "scan":
+            plan_cache = cache if isinstance(cache, PlanCache) \
+                else default_plan_cache()
             mode = "chunk" if eval_fn is not None else "none"
             # mesh descriptor: a sharded and an unsharded plan must never
-            # alias, nor two plans on meshes of different shape/axis
-            # names/silo axes
+            # alias, nor two plans on meshes of different shape/axis names
             mesh_sig = None if mesh is None else (
                 tuple(mesh.axis_names),
-                tuple(int(s) for s in mesh.devices.shape), axes)
+                tuple(int(s) for s in mesh.devices.shape))
             key = (
                 padded.num_silos, padded.num_batches, padded.batch_size,
                 tuple(padded.X.shape[2:]), str(padded.X.dtype),
                 tuple(padded.Y.shape[2:]), str(padded.Y.dtype),
-                _tree_signature(init_params),
+                tree_signature(init_params),
                 # chunk plans step nr rounds per dispatch with rounds never
                 # baked into the executable, so they are rounds-agnostic:
                 # rounds=50 and rounds=200 share one cached plan
@@ -829,35 +684,21 @@ def run_federated(
                     local_epochs=local_epochs, aggregator=aggregator,
                     per_example=per_example,
                     reset_opt=reset_opt_per_round, collect=mode,
-                    masked=True, mesh=mesh, silo_axes=axes,
-                    trim_frac=trim_frac, krum_f=krum_f),
+                    mesh=mesh, trim_frac=trim_frac, krum_f=krum_f),
                 pins=(loss_fn, opt))
-        elif engine == "scan":
-            plan = make_fl_plan(
-                num_silos=padded.num_silos, num_batches=padded.num_batches,
-                batch_size=padded.batch_size, opt=opt, batch_loss=batch_loss,
-                rounds=rounds, local_epochs=local_epochs,
-                aggregator=aggregator, per_example=per_example,
-                reset_opt=reset_opt_per_round,
-                collect="chunk" if eval_fn is not None else "none",
-                masked=needs_mask, mesh=mesh, silo_axes=axes,
-                trim_frac=trim_frac, krum_f=krum_f)
-        if plan is not None:
             args = _plan_args(padded, seed, rounds, availability=av,
                               silo_scale=scale_vec)
-    if plan is None:
+    if engine == "host":
         return _run_host(batch_loss, init_params, padded, opt=opt,
                          rounds=rounds, local_epochs=local_epochs,
                          aggregator=aggregator, seed=seed, eval_fn=eval_fn,
                          per_example=per_example,
                          reset_opt=reset_opt_per_round,
                          availability=av, silo_scale=scale_vec,
-                         trim_frac=trim_frac, krum_f=krum_f,
-                         masked=needs_mask)
+                         trim_frac=trim_frac, krum_f=krum_f)
     res = _run_scan(plan, init_params, args, rounds=rounds, eval_fn=eval_fn,
                     eval_chunk=eval_chunk)
-    if plan_cache is not None:
-        res.cache_stats = {"hit": was_hit, **plan_cache.stats()}
+    res.cache_stats = {"hit": was_hit, **plan_cache.stats()}
     return res
 
 
@@ -868,13 +709,10 @@ def run_federated(
 def _run_host(batch_loss, init_params, padded: PaddedSilos, *, opt, rounds,
               local_epochs, aggregator, seed, eval_fn, per_example,
               reset_opt, availability=None, silo_scale=None,
-              trim_frac: float = 0.2, krum_f: int = 1,
-              masked: Optional[bool] = None) -> FLResult:
+              trim_frac: float = 0.2, krum_f: int = 1) -> FLResult:
     d, nb, bs = padded.num_silos, padded.num_batches, padded.batch_size
     key = jax.random.PRNGKey(seed)
-    if masked is None:
-        masked = padded.has_padding
-    step = jax.jit(_make_sgd_step(batch_loss, opt, masked=masked))
+    step = jax.jit(_make_sgd_step(batch_loss, opt))
     grad_fn = jax.jit(jax.value_and_grad(batch_loss))
     X, Y, w = padded.X, padded.Y, padded.w
     robust = aggregator in ROBUST_AGGREGATORS
@@ -983,22 +821,11 @@ class StreamedPlan:
     carry_params: Callable
 
 
-def _resolve_collect(collect, collect_params) -> str:
-    mode = collect if collect is not None else \
-        ("stack" if collect_params else "none")
-    if mode not in ("none", "stack", "chunk"):
-        raise ValueError(f"unknown collect mode {mode!r}; "
-                         "choose 'none', 'stack', or 'chunk'")
-    return mode
-
-
 def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
                  opt: Optimizer, batch_loss, rounds: int, local_epochs: int,
                  aggregator: str = "fedavg", per_example: bool = True,
-                 reset_opt: bool = True, collect_params: bool = False,
-                 masked: bool = True, collect: Optional[str] = None,
-                 mesh=None, silo_axes: Optional[Sequence[str]] = None,
-                 trim_frac: float = 0.2, krum_f: int = 1):
+                 reset_opt: bool = True, collect: str = "none",
+                 mesh=None, trim_frac: float = 0.2, krum_f: int = 1):
     """Build a compiled whole-FL-phase PLAN: a jitted
 
         ``plan(init_params, X, Y, w, wr, scale, key) -> (final_params, ys)``
@@ -1010,12 +837,13 @@ def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
     training entirely), scale (d,) the per-silo delta multiplier
     (``apply_silo_scale``; all-ones in honest runs, the attack injection
     point otherwise), key the PRNG key that seeds the batch schedule, and
-    ys the (rounds,) loss vector. Unlike a data-closure runner, ALL tenant
-    data enters as arguments, so one plan compiles ONE executable per
-    input-shape set and every tenant whose padded shapes land in the same
-    bucket reuses it — the unit the PlanCache stores. Because wr and scale
-    are arguments too, every dropout pattern and every attack configuration
-    shares the same executable.
+    ys the (rounds,) loss vector. ALL tenant data enters as arguments, so
+    one plan compiles ONE executable per input-shape set and every tenant
+    whose padded shapes land in the same bucket reuses it — the unit the
+    PlanCache stores. Because wr and scale are arguments too, every dropout
+    pattern and every attack configuration shares the same executable.
+    Every step is masked (`_make_sgd_step`): all-padding batches, empty
+    silos and dropped silos are exact no-ops.
 
     aggregator ∈ ROBUST_AGGREGATORS swaps the round boundary from the
     weighted mean to a robust statistic over the available silos
@@ -1023,40 +851,39 @@ def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
     cache identity). Sharded robust plans all_gather the silo submissions
     instead of psumming partial weighted sums (DESIGN.md §8).
 
-    collect (back-compat bool ``collect_params`` maps onto it):
-      "none"  — ys is the (rounds,) loss vector (default).
-      "stack" — ys is (losses, per-round params stacked (rounds, |params|)).
-                LEGACY: materializes the full stack on device; kept for the
-                streamed-vs-stacked regression tests only.
+    collect (run_federated picks it from whether an eval_fn is given):
+      "none"  — ys is the (rounds,) loss vector: one dispatch per phase.
       "chunk" — returns a StreamedPlan whose step scans a CHUNK of rounds
                 and emits only that chunk's params — the bounded-memory
                 eval path (_run_scan streams chunks to host and keeps only
                 scalar metrics).
 
-    mesh/silo_axes (DESIGN.md §7): with a mesh, the whole FL phase runs
-    under shard_map with the padded silo dim sharded over silo_axes
-    (default ``default_silo_axes``: ("pod", "data") jointly when both
-    exist), params/PRNG replicated, and the entire local phase
-    collective-free per shard — each shard trains its d/shards silos with
-    their GLOBAL silo ids folded into the batch schedule, so the results
-    match the single-device plan to float tolerance. The only collectives
-    are the round-boundary weighted psums of fedavg_sync (one per leaf per
-    silo-axis level, hierarchical: intra-pod first, cross-pod second).
-    num_silos must be divisible by the silo-shard count (run_federated pads
-    with empty no-op silos)."""
+    mesh (DESIGN.md §7): with a mesh, the whole FL phase runs under
+    shard_map with the padded silo dim sharded over ``default_silo_axes``
+    (("pod", "data") jointly when both exist), params/PRNG replicated, and
+    the entire local phase collective-free per shard — each shard trains
+    its d/shards silos with their GLOBAL silo ids folded into the batch
+    schedule, so the results match the single-device plan to float
+    tolerance. The only collectives are the round-boundary weighted psums
+    of fedavg_sync (one per leaf per silo-axis level, hierarchical:
+    intra-pod first, cross-pod second). num_silos must be divisible by the
+    silo-shard count (`pad_silo_data(shards=...)` pads with empty no-op
+    silos)."""
+    if collect not in ("none", "chunk"):
+        raise ValueError(f"unknown collect mode {collect!r}; "
+                         "choose 'none' or 'chunk'")
     d, nb, bs = num_silos, num_batches, batch_size
     n_slots = nb * bs
-    mode = _resolve_collect(collect, collect_params)
     axes: Optional[Tuple[str, ...]] = None
     if mesh is not None:
-        axes = tuple(silo_axes) if silo_axes else default_silo_axes(mesh)
-        shards = num_silo_shards(mesh, axes)
+        axes = default_silo_axes(mesh)
+        shards = num_silo_shards(mesh)
         if d % shards:
             raise ValueError(
                 f"num_silos={d} is not divisible by the {shards}-way silo "
-                f"mesh {axes}; pad the silo stack (pad_silo_data min_silos, "
+                f"mesh {axes}; pad the silo stack (pad_silo_data shards=, "
                 "as run_federated does) so every shard holds d/shards silos")
-    step = _make_sgd_step(batch_loss, opt, masked=masked)
+    step = _make_sgd_step(batch_loss, opt)
     vstep = jax.vmap(step, in_axes=(0, 0, 0, 0, 0, None))
     gather = jax.vmap(lambda a, i: a[i])                 # (d, n_slots, …) × (d, B)
 
@@ -1144,8 +971,7 @@ def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
             return (gp, fs), reduce_sum(jnp.sum(wr_r * losses)), gp
         # availability suppression: w·1.0 is bit-exact for present silos,
         # absent silos get all-zero masks → every batch is an exact no-op
-        # under the masked-step guard (run_federated forces masked=True
-        # whenever any wr entry is zero)
+        # under the masked-step guard
         w_eff = w * (wr_r > 0).astype(w.dtype)[:, None]
         if reset_opt:
             gp = carry
@@ -1211,9 +1037,8 @@ def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
             return c, emit(rl, gp)
         return round_body
 
-    if mode in ("none", "stack"):
-        emit = (lambda rl, gp: (rl, gp)) if mode == "stack" \
-            else (lambda rl, gp: rl)
+    if collect == "none":
+        emit = lambda rl, gp: rl
 
         @jax.jit
         def plan(init_params, X, Y, w, wr, scale, key):
@@ -1233,7 +1058,7 @@ def make_fl_plan(*, num_silos: int, num_batches: int, batch_size: int,
 
         return plan
 
-    # mode == "chunk": the bounded-memory streamed plan; wr arrives as this
+    # collect == "chunk": the bounded-memory streamed plan; wr arrives as this
     # chunk's (nr, d) ROW SLICE (the driver slices wr[rnd0:rnd0+nr]) so
     # total rounds still never enters the executable
     def chunk_step(carry, X, Y, w, wr, scale, key, rnd0, nr):
@@ -1309,33 +1134,6 @@ def lower_fl_plan(plan, init_params, padded: PaddedSilos, *, rounds: int,
     return plan.lower(init_params, *args)
 
 
-def make_scan_runner(batch_loss, padded: PaddedSilos, *, opt, rounds,
-                     local_epochs, aggregator="fedavg", seed=0,
-                     per_example=True, reset_opt=True,
-                     collect_params=False, mesh=None,
-                     silo_axes=None, availability=None, silo_scale=None,
-                     trim_frac: float = 0.2, krum_f: int = 1) -> Callable:
-    """Back-compat data-closure wrapper over make_fl_plan: a
-    ``run(init_params) -> (final_params, ys)`` with this tenant's padded
-    stack bound. Calling the SAME runner twice reuses the compiled
-    executable — what benchmarks/fed_bench.py times as the warm FL phase.
-    With mesh, the plan runs sharded (the padded silo count must already be
-    a multiple of the silo-shard count)."""
-    dropout = availability is not None and not np.all(
-        np.asarray(availability) > 0)
-    plan = make_fl_plan(
-        num_silos=padded.num_silos, num_batches=padded.num_batches,
-        batch_size=padded.batch_size, opt=opt, batch_loss=batch_loss,
-        rounds=rounds, local_epochs=local_epochs, aggregator=aggregator,
-        per_example=per_example, reset_opt=reset_opt,
-        collect_params=collect_params,
-        masked=padded.has_padding or dropout,
-        mesh=mesh, silo_axes=silo_axes, trim_frac=trim_frac, krum_f=krum_f)
-    args = _plan_args(padded, seed, rounds, availability=availability,
-                      silo_scale=silo_scale)
-    return lambda init_params: plan(init_params, *args)
-
-
 def _fetch(tree):
     """Device arrays read to the host (counted as ``feddcl.device.fetches``,
     one per array)."""
@@ -1355,9 +1153,9 @@ def _run_scan(plan, init_params, args, *, rounds: int, eval_fn=None,
     and dropped — peak extra memory is eval_chunk × |params| regardless of
     rounds. Without eval_fn, one dispatch runs the whole phase and only
     the (rounds,) loss vector comes back."""
-    history: List[Dict[str, float]] = []
     with obs.span("feddcl.fl.plan"):
         if isinstance(plan, StreamedPlan):
+            history: List[Dict[str, float]] = []
             X, Y, w, wr, scale, key = args
             carry = plan.carry_init(init_params)
             rnd0 = 0
@@ -1369,27 +1167,16 @@ def _run_scan(plan, init_params, args, *, rounds: int, eval_fn=None,
                 host_ls, host_ps = _fetch(chunk)
                 for j in range(nr):
                     rec = {"round": rnd0 + j, "loss": float(host_ls[j])}
-                    if eval_fn is not None:
-                        rec.update(eval_fn(
-                            jax.tree.map(lambda a: a[j], host_ps)))
+                    rec.update(eval_fn(jax.tree.map(lambda a: a[j], host_ps)))
                     history.append(rec)
                 rnd0 += nr
             return FLResult(params=plan.carry_params(carry), history=history)
 
         gp, ys = plan(init_params, *args)
-        if eval_fn is not None:
-            # one host fetch for the whole (rounds, |params|) stack, not a
-            # device round trip per leaf per round; the stacked mode itself
-            # remains the legacy memory-heavy path kept for regression tests
-            round_losses, host_params = _fetch(ys)
-        else:
-            round_losses = _fetch(ys)
-    for rnd in range(rounds):
-        rec = {"round": rnd, "loss": float(round_losses[rnd])}
-        if eval_fn is not None:
-            rec.update(eval_fn(jax.tree.map(lambda a: a[rnd], host_params)))
-        history.append(rec)
-    return FLResult(params=gp, history=history)
+        round_losses = _fetch(ys)
+    return FLResult(params=gp, history=[
+        {"round": rnd, "loss": float(round_losses[rnd])}
+        for rnd in range(rounds)])
 
 
 # ==========================================================================

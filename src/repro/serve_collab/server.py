@@ -35,8 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core.federated import (PlanCache, _tree_signature, bucket_pow2,
-                                  default_plan_cache)
+from repro.core.plans import (PlanCache, bucket_pow2, default_plan_cache,
+                              tree_signature)
 from repro.core.protocol import FedDCLSetup
 from repro.models import mlp
 from repro.serve_collab.tables import TenantTable, build_tables
@@ -103,17 +103,15 @@ class ServeCollab:
 
     def __init__(self, tables: Sequence[TenantTable], params: Any, *,
                  setup: Optional[FedDCLSetup] = None,
-                 max_batch: int = 256, cache: Optional[PlanCache] = None,
-                 bucket=bucket_pow2):
+                 max_batch: int = 256, cache: Optional[PlanCache] = None):
         self.tables: List[TenantTable] = list(tables)
         self.params = params
         self.setup = setup
         self.max_batch = int(max_batch)
-        self.bucket = bucket
         self.cache = cache if isinstance(cache, PlanCache) \
             else default_plan_cache()
         self.queue: deque = deque()
-        self._psig = _tree_signature(params)
+        self._psig = tree_signature(params)
         self._next_rid = 0
         self.steps = 0
         self.rows_served = 0
@@ -198,7 +196,7 @@ class ServeCollab:
                     rows += take
                     if rows >= self.max_batch:
                         break
-                b_pad = self.bucket(rows)
+                b_pad = bucket_pow2(rows)
                 x = np.zeros((b_pad, tbl.in_dim), np.float32)
                 tix = np.zeros((b_pad,), np.int32)
                 at = 0
@@ -258,7 +256,7 @@ class ServeCollab:
         so every tenant's combined map changed — table CONTENT is runtime
         data, only a grown pow2 tenant pad can introduce a new bucket."""
         with obs.span("feddcl.serve.refresh_tables"):
-            self.tables = build_tables(self.setup, self.bucket)
+            self.tables = build_tables(self.setup)
 
     def onboard_user(self, i: int, X_new: np.ndarray,
                      Y_new: np.ndarray) -> int:

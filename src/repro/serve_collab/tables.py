@@ -17,12 +17,12 @@ enforces this on the artifact).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable, List, Sequence
+from typing import List
 
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.federated import bucket_pow2
+from repro.core.plans import bucket_pow2
 from repro.core.protocol import FedDCLSetup
 
 
@@ -52,13 +52,12 @@ def combined_user_map(setup: FedDCLSetup, i: int, j: int) -> np.ndarray:
         setup.Gs[i][j], np.float64)
 
 
-def build_table(setup: FedDCLSetup, i: int,
-                bucket: Callable[[int], int] = bucket_pow2) -> TenantTable:
+def build_table(setup: FedDCLSetup, i: int) -> TenantTable:
     """Stack group i's tenants into one padded device-resident table."""
     count = len(setup.mappings[i])
     m = setup.mappings[i][0].W.shape[0]
     m_hat = np.asarray(setup.Gs[i][0]).shape[1]
-    t_pad = bucket(count)
+    t_pad = bucket_pow2(count)
     M = np.zeros((t_pad, m, m_hat), np.float32)
     mu = np.zeros((t_pad, m), np.float32)
     for j in range(count):
@@ -67,8 +66,6 @@ def build_table(setup: FedDCLSetup, i: int,
     return TenantTable(M=jnp.asarray(M), mu=jnp.asarray(mu), count=count)
 
 
-def build_tables(setup: FedDCLSetup,
-                 bucket: Callable[[int], int] = bucket_pow2
-                 ) -> List[TenantTable]:
+def build_tables(setup: FedDCLSetup) -> List[TenantTable]:
     """One table per DC group."""
-    return [build_table(setup, i, bucket) for i in range(setup.num_groups)]
+    return [build_table(setup, i) for i in range(setup.num_groups)]

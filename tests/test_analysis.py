@@ -303,8 +303,8 @@ _FAKE_HLO = """
 
 
 def _inline_histogram(txt):
-    # the exact counter tests/test_fed_sharded.py and benchmarks/fed_bench.py
-    # used before PR 9 — census must match it token for token
+    # the inline counter the sharded-plan tests used before
+    # collective_census existed — census must match it token for token
     out = {}
     for kind in ("all-reduce", "all-gather", "all-to-all",
                  "collective-permute", "reduce-scatter"):
@@ -393,8 +393,7 @@ def test_streamed_chunk_plan_audits_clean():
     plan = federated.make_fl_plan(
         num_silos=padded.num_silos, num_batches=padded.num_batches,
         batch_size=padded.batch_size, opt=adamw(1e-2), batch_loss=bl,
-        rounds=4, local_epochs=1, aggregator="fedavg", masked=True,
-        collect="chunk")
+        rounds=4, local_epochs=1, aggregator="fedavg", collect="chunk")
     lowered = lower_fl_plan(plan, params, padded, rounds=4)
     assert_no_baked_data(lowered, min_elems=256)
     assert collective_census(lowered) == {}

@@ -109,10 +109,9 @@ def test_scan_matches_host_with_eval_and_sgd_train():
                          ids=["two-dispatches", "one-dispatch"])
 def test_streamed_eval_history_matches_stacked(eval_chunk):
     """Regression for the bounded-memory eval path: the chunked streamed
-    history must match (a) the legacy collect="stack" plan that
-    materialized a (rounds, |params|) stack on device, and (b) the host
-    engine — both per-round losses and eval_fn outputs. eval_chunk=4 with
-    rounds=6 exercises the ragged final dispatch (4 + 2)."""
+    history must match the host engine on the one padded layout — per-round
+    losses, eval_fn outputs and final params. eval_chunk=4 with rounds=6
+    exercises the ragged final dispatch (4 + 2)."""
     silos = _linear_silos([40, 28, 52], seed=3)
     params = _params(seed=1)
     ev = lambda p: {"w0": float(np.asarray(
@@ -123,22 +122,11 @@ def test_streamed_eval_history_matches_stacked(eval_chunk):
                          **kw)
     scan = run_federated(_reg_loss, params, silos, engine="scan", eval_fn=ev,
                          eval_chunk=eval_chunk, **kw)
-    # the OLD stacked path, driven through the same runner
-    padded = pad_silo_data(silos, 16)
-    batch_loss = federated._make_batch_loss(_reg_loss, True, 0.0)
-    stacked_plan = federated.make_fl_plan(
-        num_silos=padded.num_silos, num_batches=padded.num_batches,
-        batch_size=padded.batch_size, opt=adamw(1e-2), batch_loss=batch_loss,
-        rounds=6, local_epochs=2, collect="stack", masked=padded.has_padding)
-    legacy = federated._run_scan(
-        stacked_plan, params, federated._plan_args(padded, 7, 6), rounds=6,
-        eval_fn=ev)
-    assert len(scan.history) == len(legacy.history) == 6
-    for s, l, h in zip(scan.history, legacy.history, host.history):
-        assert abs(s["w0"] - l["w0"]) < 1e-6
-        assert abs(s["loss"] - l["loss"]) < 1e-6 * max(1.0, abs(l["loss"]))
+    assert len(scan.history) == len(host.history) == 6
+    for s, h in zip(scan.history, host.history):
+        assert s["round"] == h["round"]
         assert abs(s["w0"] - h["w0"]) < 1e-4
-    assert _max_rel_diff(scan.params, legacy.params) < 1e-6
+        assert abs(s["loss"] - h["loss"]) < 1e-4 * max(1.0, abs(h["loss"]))
     assert _max_rel_diff(scan.params, host.params) < 1e-4
 
 
@@ -222,7 +210,7 @@ def test_all_padding_batch_is_exact_noop():
     params = _params(seed=7)
     opt = adamw(1e-2)
     batch_loss = federated._make_batch_loss(_reg_loss, True, 0.0)
-    step = federated._make_sgd_step(batch_loss, opt, masked=True)
+    step = federated._make_sgd_step(batch_loss, opt)
     x = jnp.full((8, 4), 1e3)                            # garbage padding
     y = jnp.zeros((8, 1))
     w0 = jnp.zeros((8,))

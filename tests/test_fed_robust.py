@@ -278,8 +278,9 @@ def test_grad_scale_attack_breaks_fedavg_not_robust():
 def test_label_flip_attack_robust_beats_fedavg():
     """Data poisoning: judge the FINAL GLOBAL MODEL on honest data — the
     reported round loss averages in the corrupted silo's own (unfittable)
-    objective, which masks the damage to everyone else."""
-    silos = _linear_silos([48, 48, 48, 48, 48], seed=10)
+    objective, which masks the damage to everyone else. 64-row silos fill
+    their 4 batches of 16, so every epoch is 4 full-batch steps."""
+    silos = _linear_silos([64, 64, 64, 64, 64], seed=10)
     flipped = privacy.label_flip_silos(silos, [1])
     params = _params(seed=5)
     kw = dict(opt=adamw(1e-2), rounds=12, local_epochs=2, batch_size=16,
@@ -351,7 +352,7 @@ def test_unsharded_plan_collective_free_and_data_free(aggregator):
     plan = federated.make_fl_plan(
         num_silos=padded.num_silos, num_batches=padded.num_batches,
         batch_size=padded.batch_size, opt=adamw(1e-2), batch_loss=bl,
-        rounds=2, local_epochs=2, aggregator=aggregator, masked=True)
+        rounds=2, local_epochs=2, aggregator=aggregator)
     lowered = plan.lower(params, *federated._plan_args(padded, 0, 2))
     assert collective_census(lowered) == {}
     assert_no_baked_data(lowered, min_elems=256)

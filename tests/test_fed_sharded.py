@@ -200,7 +200,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     # hierarchy level — and the count must not change with local_epochs (a
     # leak of collectives into the local phase would scale with E).
     batch_loss = federated._make_batch_loss(loss, True, 0.0)
-    padded = pad_silo_data(silos, 8, min_silos=8)
+    padded = pad_silo_data(silos, 8, shards=8)
     args = federated._plan_args(padded, 3, 2)
 
     def hist(epochs, aggregator):
@@ -208,7 +208,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
             num_silos=padded.num_silos, num_batches=padded.num_batches,
             batch_size=padded.batch_size, opt=adamw(1e-2),
             batch_loss=batch_loss, rounds=2, local_epochs=epochs,
-            aggregator=aggregator, masked=True, mesh=mesh)
+            aggregator=aggregator, mesh=mesh)
         lowered = plan.lower(params, *args)
         # piggyback the privacy audit: no plan flavor may bake tenant data
         assert_no_baked_data(lowered, min_elems=512)

@@ -15,8 +15,7 @@ import numpy as np
 import pytest
 
 from repro.core import federated
-from repro.core.federated import (PlanCache, bucket_pow2, pad_silo_data,
-                                  run_federated)
+from repro.core.federated import PlanCache, pad_silo_data, run_federated
 from repro.models import mlp
 from repro.optim import adamw
 
@@ -78,17 +77,41 @@ def test_warm_hit_agrees_with_cold_run():
 def test_cached_scan_matches_host_on_bucketed_layout():
     silos = _silos(3, 20, seed=0)
     res = _run(silos, PlanCache())
-    bs = KW["batch_size"]
-    n_max = max(x.shape[0] for x, _ in silos)
-    padded = pad_silo_data(silos, bs,
-                           min_batches=bucket_pow2(-(-n_max // bs)),
-                           min_silos=bucket_pow2(len(silos)))
-    batch_loss = federated._make_batch_loss(_loss, True, 0.0)
-    host = federated._run_host(
-        batch_loss, _params(), padded, opt=adamw(1e-2), rounds=KW["rounds"],
-        local_epochs=KW["local_epochs"], aggregator="fedavg", seed=0,
-        eval_fn=None, per_example=True, reset_opt=True)
+    host = _run(silos, None, engine="host")
     np.testing.assert_allclose(_flat(res), _flat(host), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("own_cache", [False, True],
+                         ids=["process-cache", "own-cache"])
+def test_host_and_scan_train_on_one_layout(own_cache, tmp_path):
+    """Both engines pad a ragged 3-silo tenant (40/28/52 rows, batch 8) to
+    the same bucketed layout — 4 silos x 8 batches x 8 slots — so they
+    count the same slot-steps and train the same params, whichever plan
+    cache the scan engine compiles into."""
+    from repro import obs
+
+    r = np.random.default_rng(3)
+    wt = r.standard_normal((M, 1))
+    silos = []
+    for n in (40, 28, 52):
+        X = r.standard_normal((n, M))
+        silos.append((X, X @ wt + 0.01 * r.standard_normal((n, 1))))
+    kw = dict(opt=adamw(1e-2), rounds=2, local_epochs=2, batch_size=8,
+              seed=5)
+    slot_steps, params = {}, {}
+    for engine, cache in (("host", None),
+                          ("scan", PlanCache() if own_cache else True)):
+        obs.clear()
+        with jax.profiler.trace(str(tmp_path / engine)):
+            res = run_federated(_loss, _params(), silos, engine=engine,
+                                cache=cache, **kw)
+        slot_steps[engine] = obs.snapshot()["counters"][
+            "feddcl.fl.slot_steps"]
+        params[engine] = _flat(res)
+    obs.clear()
+    assert slot_steps["host"] == slot_steps["scan"] == 4 * 64 * 2 * 2
+    np.testing.assert_allclose(params["scan"], params["host"], rtol=0,
+                               atol=1e-5)
 
 
 def test_warm_plan_cache_hit_compiles_nothing():
@@ -188,6 +211,10 @@ def test_chunk_mode_plan_is_rounds_agnostic():
 def test_cache_requires_scan_engine():
     with pytest.raises(ValueError):
         _run(_silos(2, 10), PlanCache(), engine="host")
+    with pytest.raises(ValueError):
+        _run(_silos(2, 10), True, engine="host")
+    with pytest.raises(ValueError):
+        _run(_silos(2, 10), False)
 
 
 # ---------------------------------------------------------------------------
@@ -195,7 +222,7 @@ def test_cache_requires_scan_engine():
 # ---------------------------------------------------------------------------
 
 def test_sample_counts_stay_integral():
-    padded = pad_silo_data(_silos(3, 20), 8, min_silos=4)
+    padded = pad_silo_data(_silos(3, 20), 8)
     assert np.issubdtype(padded.sizes.dtype, np.integer)
     assert padded.sizes.tolist() == [20, 23, 26, 0]   # bucket silo: size 0
     big = np.array([2 ** 24 + 1, 2 ** 24], np.int64)
