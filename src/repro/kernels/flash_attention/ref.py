@@ -28,4 +28,4 @@ def mha_reference(q, k, v, *, causal: bool = True, window: int = 0,
     logits = jnp.where(mask[None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     ctx = jnp.einsum("bhgqs,bhsk->bhgqk", probs, v)
-    return ctx.reshape(B, H, Sq, hd)
+    return ctx.reshape(B, H, Sq, v.shape[-1])

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, SSMConfig
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.shardingx.constrain import constrain
 
 Params = Dict[str, Any]
@@ -150,7 +151,6 @@ def multi_head_attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     k = apply_rope(k, sin_b, cos_b)
 
     if use_pallas:
-        from repro.kernels.flash_attention import ops as fa_ops
         ctx = fa_ops.flash_attention(
             q, k, v, causal=True,
             window=cfg.sliding_window if bool(is_local) else 0,
@@ -243,6 +243,22 @@ def sdpa(q, k, v, *, q_pos, k_pos, is_local, window, softcap) -> jnp.ndarray:
                              is_local=is_local, window=window, softcap=softcap)
     return sdpa_reference(q, k, v, q_pos=q_pos, k_pos=k_pos, is_local=is_local,
                           window=window, softcap=softcap)
+
+
+def causal_flash_applies(seq: int, *, is_local, attn_variant: str,
+                         softcap: float) -> bool:
+    """Whether full-sequence attention over positions 0..seq-1 runs as the
+    fused causal kernel (``fa_ops.causal_attention``) in place of ``sdpa``:
+    on a TPU, at a length the kernel's blocks tile, with no softcap and no
+    sliding window. A layer flag traced under the layer scan comes from
+    ``backbone._local_flags``, false on every layer of the "full" variant."""
+    if jax.default_backend() != "tpu" or softcap:
+        return False
+    if seq < 128 or seq % 128 or seq % fa_ops.causal_block(seq):
+        return False
+    if isinstance(is_local, jax.core.Tracer):
+        return attn_variant == "full"
+    return not bool(is_local)
 
 
 def sdpa_reference(q, k, v, *, q_pos, k_pos, is_local, window, softcap) -> jnp.ndarray:
@@ -384,7 +400,11 @@ def mla_attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
                   positions: jnp.ndarray, is_local=False,
                   return_kv: bool = False):
     """Training/prefill MLA (naive expanded form). x: (B, S, d).
-    With return_kv, also returns the latent cache entries (ckv, k_rope)."""
+    With return_kv, also returns the latent cache entries (ckv, k_rope).
+
+    On a TPU the attention core is the fused causal kernel wherever
+    ``causal_flash_applies``; its mask is exact because every full-sequence
+    caller takes ``positions`` from ``backbone.embed_inputs``, arange(S)."""
     m = cfg.mla
     B, S, d = x.shape
     H = cfg.num_heads
@@ -411,10 +431,13 @@ def mla_attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     q_eff = constrain(q_eff, "batch", None, "model", None)
     k_eff = constrain(k_eff, "batch", None, "model", None)
     v = constrain(v, "batch", None, "model", None)
-    dim_eff = m.qk_nope_head_dim + m.qk_rope_head_dim
-    # sdpa scales by 1/sqrt(dim_eff) — matches MLA's scale over (nope+rope)
-    ctx = sdpa(q_eff, k_eff, v, q_pos=positions, k_pos=positions,
-               is_local=is_local, window=cfg.sliding_window, softcap=0.0)
+    # both paths scale by 1/sqrt(nope + rope), MLA's scale
+    if causal_flash_applies(S, is_local=is_local,
+                            attn_variant=cfg.attn_variant, softcap=0.0):
+        ctx = fa_ops.causal_attention(q_eff, k_eff, v)
+    else:
+        ctx = sdpa(q_eff, k_eff, v, q_pos=positions, k_pos=positions,
+                   is_local=is_local, window=cfg.sliding_window, softcap=0.0)
     out = jnp.einsum("bqhk,hkd->bqd", ctx, p["wo"].astype(x.dtype))
     if return_kv:
         return out, (ckv, k_rope[:, :, 0, :])

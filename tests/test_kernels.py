@@ -9,6 +9,7 @@ from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.gmm import ops as gmm_ops, ref as gmm_ref
 from repro.kernels.gram import ops as gram_ops, ref as gram_ref
 from repro.kernels.rwkv6 import ops as rwkv_ops, ref as rwkv_ref
+from repro.models import layers as L
 
 
 # --------------------------------------------------------------------------
@@ -63,6 +64,46 @@ def test_flash_attention_blocks_smaller_than_seq():
     ref = fa_ops.flash_attention(q, k, v, backend="ref")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
                                rtol=2e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_causal_attention_and_its_vjp(dtype, tol):
+    """The trainable causal kernel (splash, interpret mode) and its jnp
+    reference against the model's masked f32-softmax attention, forward
+    and the kernel's gradients of q, k and v, at MLA's head widths (qk 192,
+    v 128) over two 1024-blocks, so the block past the causal frontier is
+    skipped."""
+    B, S, H = 2, 2048, 2
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (B, S, H, 192), dtype)
+    k = jax.random.normal(ks[1], (B, S, H, 192), dtype)
+    v = jax.random.normal(ks[2], (B, S, H, 128), dtype)
+    probe = jax.random.normal(ks[3], (B, S, H, 128), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(S), (B, S))
+
+    def reference(q, k, v):
+        return L.sdpa_reference(q, k, v, q_pos=pos, k_pos=pos,
+                                is_local=False, window=0, softcap=0.0)
+
+    def kernel(q, k, v):
+        return fa_ops.causal_attention(q, k, v, backend="interpret")
+
+    def loss(fn):
+        return lambda *a: jnp.sum(probe * fn(*a).astype(jnp.float32))
+
+    def close(got, want):
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        scale = np.abs(want).max()
+        np.testing.assert_allclose(got / scale, want / scale, atol=tol)
+
+    got = kernel(q, k, v)
+    assert got.shape == (B, S, H, 128) and got.dtype == dtype
+    close(got, reference(q, k, v))
+    close(fa_ops.causal_attention(q, k, v, backend="ref"), reference(q, k, v))
+    for a, b in zip(jax.grad(loss(kernel), (0, 1, 2))(q, k, v),
+                    jax.grad(loss(reference), (0, 1, 2))(q, k, v)):
+        close(a, b)
 
 
 # --------------------------------------------------------------------------

@@ -14,6 +14,7 @@ import _mla_moe_ref as ref
 from repro.configs import REDUCED
 from repro.configs.base import FederatedConfig, InputShape, TrainConfig
 from repro.core.federated import silo_replicate
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.launch.train import compile_round, run_rounds
 from repro.models import backbone as bb
 from repro.models import layers as L
@@ -63,6 +64,71 @@ def test_mla_attention_matches_reference(arch):
         want = ref.mla(p, h, cfg)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("arch", ["moonlight-16b-a3b", "deepseek-v3-671b"])
+def test_mla_attention_on_the_causal_kernel_matches_reference(arch,
+                                                              monkeypatch):
+    """The kernel path forced on the CPU (splash in interpret mode) over
+    two 1024-blocks."""
+    monkeypatch.setattr(L, "causal_flash_applies", lambda *a, **k: True)
+    monkeypatch.setattr(fa_ops, "_resolve", lambda backend: "interpret")
+    cfg = REDUCED[arch]
+    key = jax.random.PRNGKey(1)
+    p = L.init_mla(key, cfg, jnp.float32)
+    h = jax.random.normal(jax.random.fold_in(key, 2), (1, 2048, cfg.d_model))
+    pos = jnp.broadcast_to(jnp.arange(2048), (1, 2048))
+    with jax.default_matmul_precision("highest"):
+        got = L.mla_attention(p, h, cfg, positions=pos)
+        want = ref.mla(p, h, cfg)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("backend,seq,flag,variant,softcap,kernel", [
+    ("cpu", 4096, None, "full", 0.0, False),
+    ("tpu", 4608, None, "full", 0.0, False),      # 4.5 blocks of 1024
+    ("tpu", 4096, True, "full", 0.0, False),      # a sliding-window layer
+    ("tpu", 4096, None, "alternating", 0.0, False),
+    ("tpu", 4096, None, "full", 50.0, False),
+    ("tpu", 4096, None, "full", 0.0, True),
+    ("tpu", 4096, False, "full", 0.0, True),
+], ids=["not-tpu", "seq-not-block-multiple", "sliding-window",
+        "traced-window-flag", "softcap", "tpu", "tpu-flag-false"])
+def test_full_sequence_attention_routing(backend, seq, flag, variant,
+                                         softcap, kernel, monkeypatch):
+    """`mla_attention` takes the causal kernel only on a TPU, at a length
+    its blocks tile, with no window and no softcap; anything else keeps
+    `sdpa`. A `None` flag is the layer scan's traced one."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if softcap:                  # mla_attention itself takes no softcap
+        assert L.causal_flash_applies(seq, is_local=False,
+                                      attn_variant=variant, softcap=0.0)
+        assert not L.causal_flash_applies(seq, is_local=False,
+                                          attn_variant=variant,
+                                          softcap=softcap)
+        return
+    cfg = CFG.with_overrides(attn_variant=variant)
+    taken = []
+
+    def route(name):
+        def core(q, k, v, **_):
+            taken.append(name)
+            return jnp.zeros(q.shape[:3] + v.shape[3:], q.dtype)
+        return core
+
+    monkeypatch.setattr(L, "sdpa", route("sdpa"))
+    monkeypatch.setattr(fa_ops, "causal_attention", route("kernel"))
+    p = L.init_mla(jax.random.PRNGKey(0), cfg, jnp.float32)
+    h = jax.ShapeDtypeStruct((1, seq, cfg.d_model), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(seq), (1, seq))
+    if flag is None:
+        jax.eval_shape(lambda h, f: L.mla_attention(
+            p, h, cfg, positions=pos, is_local=f), h, jnp.zeros((), bool))
+    else:
+        jax.eval_shape(lambda h: L.mla_attention(
+            p, h, cfg, positions=pos, is_local=flag), h)
+    assert taken == ["kernel" if kernel else "sdpa"]
 
 
 def test_prefill_then_mla_decode_matches_the_full_forward():

@@ -11,9 +11,10 @@ load the TPU library and every pytest worker imports this file.
 Widths: the Gram stacks the chip smoke reaches at the paper's MNIST layout
 (group stacks d=5 × (c·m̃=200), the central and onboarding stacks at 250,
 battery_small's 16), flash attention at llama3.2-1b's heads, wkv6 at
-rwkv6-3b's, and the grouped matmul, forward and backward under the silo
-vmap, at moonlight-16b-a3b's expert widths with the rows of 4 × 4096
-tokens × top-6.
+rwkv6-3b's, and, forward and backward under the silo vmap at
+moonlight-16b-a3b's widths, the grouped matmul (expert widths, the rows of
+4 × 4096 tokens × top-6) and the causal splash kernels (MLA's 16 heads of
+qk 192 and v 128 over 4 × 4096 tokens).
 """
 import os
 from functools import partial
@@ -24,7 +25,8 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 
 from repro.configs import ARCHS
-from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.flash_attention.ops import (causal_attention,
+                                               flash_attention)
 from repro.kernels.gmm.ops import grouped_matmul
 from repro.kernels.gram.ops import gram_batched
 from repro.kernels.rwkv6.ops import wkv6
@@ -108,3 +110,22 @@ def test_grouped_matmul_and_its_vjp_compile_for_v5e(one_chip):
                           shape(rows, d), shape(held, d, 2 * f),
                           shape(held, f, d), shape(held, dtype=jnp.int32))
     assert "tpu_custom_call" in text and "grouped_matmul_t_pallas" in text
+
+
+def test_causal_attention_and_its_vjp_compile_for_v5e(one_chip):
+    cfg = ARCHS["moonlight-16b-a3b"]
+    m, heads = cfg.mla, cfg.num_heads
+    qk = m.qk_nope_head_dim + m.qk_rope_head_dim
+
+    def loss(q, k, v):
+        out = causal_attention(q, k, v, backend="pallas")
+        return jnp.sum(out.astype(jnp.float32))
+
+    def shape(width):
+        return jax.ShapeDtypeStruct((1, 4, 4096, heads, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = _compiled_text(jax.vmap(jax.grad(loss, argnums=(0, 1, 2))),
+                          shape(qk), shape(qk), shape(m.v_head_dim))
+    assert "tpu_custom_call" in text
+    assert all(f"splash_mha_{k}" in text for k in ("fwd", "dq", "dkv"))
